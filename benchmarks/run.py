@@ -54,6 +54,8 @@ def main() -> None:
                          "PATH after --smoke, accumulating the bench "
                          "trajectory across runs")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         from repro.core import parse_mask

@@ -2,7 +2,7 @@
 exact float32 re-rank), compare recall and bytes against the float32 tier,
 and show the rerank_k knob and quantized save/load.
 
-    PYTHONPATH=src python examples/compression.py
+    PYTHONPATH=src python examples/vector_compression.py
 """
 import sys
 import tempfile

@@ -120,7 +120,8 @@ def exact_rerank(queries, cand_vecs, cand_ids, *, k: int):
     distance is +inf come back as NO_EDGE (fewer than k qualifiers)."""
     q = queries.astype(jnp.float32)
     diff = cand_vecs.astype(jnp.float32) - q[:, None, :]
-    dist = jnp.einsum("qrd,qrd->qr", diff, diff)
+    dist = jnp.einsum("qrd,qrd->qr", diff, diff,
+                      precision=jax.lax.Precision.HIGHEST)
     dist = jnp.where(cand_ids >= 0, dist, jnp.inf)
     neg, pos = jax.lax.top_k(-dist, k)
     ids = jnp.where(jnp.isfinite(neg),

@@ -90,6 +90,12 @@ def _next_pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length()
 
 
+def _backend() -> str:
+    """The JAX platform the engine's arrays and programs live on."""
+    import jax
+    return jax.default_backend()
+
+
 # Deprecated shims (today: bare QueryEngine constructor knobs) warn exactly
 # once per process per shim: serving loops that still cross a shim don't spam
 # one warning per request, while the first crossing is always visible (and
@@ -133,7 +139,9 @@ class EngineConfig:
     Parameters
     ----------
     use_kernel : bool
-        Route distance evaluation through the Pallas kernels.
+        Route distance evaluation through the Pallas kernels. On a TPU the
+        graph route refuses it (its fused step kernel needs the whole vector
+        table in VMEM); the flat route's scan kernels run.
     route : str
         Default routing policy: ``auto`` | ``graph`` | ``pruned`` | ``flat``.
         A request's ``route`` overrides it per call.
@@ -405,6 +413,13 @@ class QueryEngine:
         return self._store_dev
 
     def graph_dev(self, variant: str) -> DeviceVariant:
+        if self.use_kernel and _backend() == "tpu":
+            # the fused wavefront step (kernels/gathered_topk.py) takes the
+            # whole (n, d) table as one VMEM block; a real corpus cannot fit
+            raise NotImplementedError(
+                "use_kernel=True cannot serve the graph route on a TPU: the "
+                "gathered_topk kernel holds the whole vector table in VMEM. "
+                "Serve graph traffic from an engine with use_kernel=False.")
         if variant not in self._graph_dev:
             fv = self.index.variants[variant]
             self._graph_dev[variant] = (
@@ -700,8 +715,7 @@ class QueryEngine:
             return max(1, int(fanout))
         if self.graph_fanout:
             return max(1, int(self.graph_fanout))
-        import jax
-        if jax.default_backend() == "tpu":
+        if _backend() == "tpu":
             return max(1, min(8, ef // 16))
         return 1
 

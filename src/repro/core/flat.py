@@ -29,13 +29,16 @@ from . import segment_tree as st
 from .hnsw import NO_EDGE
 
 INF = jnp.inf
+# Exact routes contract at full float32: a TPU's default f32 matmul is one
+# bf16 pass, which reorders near neighbours against the float32 reference.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _pairwise_l2(queries: jnp.ndarray, corpus: jnp.ndarray) -> jnp.ndarray:
     """(Q, d) x (N, d) -> (Q, N) squared L2 via the MXU-friendly expansion."""
     qn = jnp.sum(queries * queries, axis=1, keepdims=True)
     cn = jnp.sum(corpus * corpus, axis=1)
-    return qn - 2.0 * (queries @ corpus.T) + cn[None, :]
+    return qn - 2.0 * jnp.dot(queries, corpus.T, precision=HIGHEST) + cn[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("mask", "k", "use_kernel"))
@@ -81,7 +84,7 @@ def flat_search_blocked(corpus, lo, hi, queries, ql, qh, *, mask: int, k: int,
         l = jax.lax.dynamic_slice_in_dim(lo, i * block, block, 0)
         h = jax.lax.dynamic_slice_in_dim(hi, i * block, block, 0)
         cn = jnp.sum(c * c, axis=1)
-        dist = qn - 2.0 * (queries @ c.T) + cn[None, :]
+        dist = qn - 2.0 * jnp.dot(queries, c.T, precision=HIGHEST) + cn[None, :]
         sel = iv.eval_predicate(mask, l[None, :], h[None, :],
                                 ql[:, None], qh[:, None])
         dist = jnp.where(sel, dist, INF)
@@ -183,7 +186,7 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
                     + arrays["code_sq_norm"][cand_safe])
         else:
             diff = vectors[cand_safe] - queries[:, None, :]
-            dist = jnp.einsum("qbd,qbd->qb", diff, diff)
+            dist = jnp.einsum("qbd,qbd->qb", diff, diff, precision=HIGHEST)
         dist = jnp.where(sel, dist, INF)
         cat_d = jnp.concatenate([top_d, dist], axis=1)
         cat_i = jnp.concatenate([top_i, jnp.where(sel, cand, NO_EDGE)], axis=1)

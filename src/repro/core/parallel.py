@@ -9,7 +9,9 @@ deadlock-prone. Workers re-import the repro build modules (numpy-only on
 the build path, so startup stays sub-second) and stream their own
 rate-limited :mod:`repro.obs` progress lines to stderr; the parent
 aggregates completion into one ``<label>_pool`` progress line per finished
-task plus a per-task wall-clock report for bench attribution.
+task plus a per-task wall-clock report for bench attribution. Workers pin
+JAX to the CPU before any task runs: the parent may hold an accelerator,
+and a chip belongs to one process at a time.
 
 ``run_build_pool`` degrades, never errors, on *pool* problems: if the
 platform cannot spawn workers (sandboxes without process semaphores, broken
@@ -19,6 +21,8 @@ raised by the task function itself propagate unchanged.
 from __future__ import annotations
 
 import multiprocessing
+import os
+import sys
 import time
 from concurrent.futures import FIRST_COMPLETED, ProcessPoolExecutor, wait
 from concurrent.futures.process import BrokenProcessPool
@@ -27,6 +31,14 @@ from typing import Any, Callable, List, Optional, Sequence
 from repro.obs.log import get_logger
 
 logger = get_logger(__name__)
+
+
+def _cpu_only_worker() -> None:
+    """Pool initializer: keep the worker's JAX (imported with the task's
+    modules) off the accelerator the parent holds."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
 
 
 def pool_size(workers: int, n_tasks: int) -> int:
@@ -52,7 +64,8 @@ def run_build_pool(fn: Callable[[Any], Any], tasks: Sequence[Any], *,
         return None
     try:
         ctx = multiprocessing.get_context("spawn")
-        with ProcessPoolExecutor(max_workers=n_pool, mp_context=ctx) as ex:
+        with ProcessPoolExecutor(max_workers=n_pool, mp_context=ctx,
+                                 initializer=_cpu_only_worker) as ex:
             t_start = time.perf_counter()
             futs = {ex.submit(fn, t): i for i, t in enumerate(tasks)}
             out: List[Any] = [None] * len(tasks)

@@ -118,7 +118,8 @@ def _batched_l2(queries: jnp.ndarray, cand_vecs: jnp.ndarray) -> jnp.ndarray:
     """(Q, d) x (Q, S, d) -> (Q, S) squared L2. jnp fallback; the Pallas path
     is selected in repro.kernels.ops."""
     diff = cand_vecs - queries[:, None, :]
-    return jnp.einsum("qsd,qsd->qs", diff, diff)
+    return jnp.einsum("qsd,qsd->qs", diff, diff,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _dist_fn(use_kernel: bool):

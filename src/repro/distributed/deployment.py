@@ -14,7 +14,9 @@ Three shard layouts:
 * :meth:`ShardedDeployment.build` — contiguous corpus slices, one
   :class:`repro.core.MSTGIndex` + :class:`repro.core.QueryEngine` per shard
   (every engine route available per shard; local ids are rebased to global
-  row ids).
+  row ids). Shard ``i``'s engine stages its arrays on, and runs on, the
+  ``i``-th device of the mesh's corpus axis (of ``jax.devices()`` without a
+  mesh, round-robin).
 * :meth:`ShardedDeployment.from_segmented` — an existing
   :class:`repro.streaming.SegmentedIndex`'s frozen segments dealt round-robin
   onto shards (the delta buffer rides on shard 0). A snapshot view: segments
@@ -42,9 +44,11 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+import jax
 
 from repro import obs
 from repro.core.api import (IndexSpec, RouteReport, SearchRequest,
@@ -140,6 +144,8 @@ class _Shard:
     n: int
     id_offset: Optional[int]       # local row -> global id shift; None = the
     #                                engine already returns external ids
+    device: Any = None             # where the engine's arrays live (None =
+    #                                JAX's default device)
 
 
 def _shard_build_task(args):
@@ -153,6 +159,17 @@ def _shard_build_task(args):
     idx = MSTGIndex.build(ispec, vectors, lo, hi)
     arrays, meta = idx.to_payload()
     return i, arrays, meta, time.perf_counter() - t0
+
+
+def _shard_devices(mesh, axis: str, n_shards: int) -> list:
+    """One device per shard: along ``axis`` of ``mesh`` when one is
+    attached, else ``jax.devices()`` round-robin."""
+    if mesh is not None:
+        devs = np.moveaxis(np.asarray(mesh.devices),
+                           mesh.axis_names.index(axis), 0)
+        return list(devs.reshape(devs.shape[0], -1)[:n_shards, 0])
+    devs = jax.devices()
+    return [devs[i % len(devs)] for i in range(n_shards)]
 
 
 def _host_merge(ids: np.ndarray, dists: np.ndarray, k: int
@@ -242,13 +259,19 @@ class ShardedDeployment:
                 indexes.append(
                     MSTGIndex.build(ispec, vectors[a:b], lo[a:b], hi[a:b]))
                 shard_secs.append(time.perf_counter() - t0)
-        shards = [_Shard(f"shard-{i}",
-                         QueryEngine(idx, config=spec.engine), b - a, a)
-                  for i, (idx, (a, b)) in enumerate(zip(indexes, slices))]
+        shards = []
+        for i, (idx, (a, b), dev) in enumerate(zip(
+                indexes, slices,
+                _shard_devices(mesh, spec.corpus_axis, spec.n_shards))):
+            with jax.default_device(dev):
+                engine = QueryEngine(idx, config=spec.engine)
+            shards.append(_Shard(f"shard-{i}", engine, b - a, a, dev))
         wall = time.perf_counter() - t_wall
         self = cls(shards, spec, mesh)
         self.build_report = {
-            "pool_size": pool_size(spec.build_workers, spec.n_shards),
+            # 0 when the pool fell back to the serial loop
+            "pool_size": (pool_size(spec.build_workers, spec.n_shards)
+                          if results is not None else 0),
             "wall_s": wall,
             "shard_seconds": shard_secs,
             "rows_per_sec": n / wall if wall > 0 else 0.0,
@@ -419,9 +442,10 @@ class ShardedDeployment:
         else:
             # the graph route's beam pool is ef wide; keep ef >= k' so the
             # narrowed fan-in never truncates below the requested width
-            res = shard.engine.execute(dataclasses.replace(
-                request, k=min(k_loc, max(shard.n, 1)),
-                ef=max(request.ef, k_loc)))
+            with jax.default_device(shard.device):
+                res = shard.engine.execute(dataclasses.replace(
+                    request, k=min(k_loc, max(shard.n, 1)),
+                    ef=max(request.ef, k_loc)))
             li, ld, rep = (np.asarray(res.ids, np.int64),
                            np.asarray(res.dists), res.report)
         if li.shape[1] < k_loc:      # tiny shard: pad to the uniform width

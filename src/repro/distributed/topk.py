@@ -29,20 +29,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.flat import flat_search
 from repro.core.hnsw import NO_EDGE
-
-
-def _axis_size(axis: str) -> int:
-    """Static size of a named mesh axis inside shard_map.
-
-    jax.lax.axis_size only exists on newer JAX; on 0.4.x the axis env exposes
-    the (already static) size via jax.core.axis_frame."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis))
-    return int(jax.core.axis_frame(axis))
 
 
 def _pad_to_k(ids, dists, k: int):
@@ -81,7 +70,7 @@ def tournament_topk_merge(ids, dists, k: int, axis: str):
     narrow local width k' < k widens toward k instead of truncating — the
     final list is bit-identical to :func:`global_topk_merge` whenever
     distances are distinct."""
-    D = _axis_size(axis)
+    D = int(jax.lax.axis_size(axis))
     rounds = int(np.log2(D))
     assert (1 << rounds) == D, "tournament merge needs power-of-two shards"
     for r in range(rounds):
@@ -138,10 +127,10 @@ def sharded_topk_merge(mesh: Mesh, ids, dists, k: int, *,
                  else jnp.asarray(alive, bool))
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None), P(None)),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False)
+        check_vma=False)
     def run(i, d, a):
         i, d = i[0], d[0]                       # (Q, k') local slice
         ok = a[jax.lax.axis_index(axis)]
@@ -176,11 +165,11 @@ def sharded_flat_topk(mesh: Mesh, corpus, lo, hi, queries, ql, qh, *, mask: int,
                  else jnp.asarray(alive, bool))
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(corpus_axis, None), P(corpus_axis), P(corpus_axis),
                   P(None, None), P(None), P(None), P(None)),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False)
+        check_vma=False)
     def run(c, l, h, q, a, b, ok):
         ids, d = flat_search(c, l, h, q, a, b, mask=mask, k=k_loc,
                              use_kernel=use_kernel)

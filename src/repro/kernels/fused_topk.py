@@ -7,8 +7,14 @@ that every grid step aliases (out block index 0) — the (Q, N) distance matrix
 never exists, in VMEM or HBM. This is the §Perf-iteration-6 engine as a single
 kernel: HBM traffic = corpus + queries + (Q, 2k) outputs.
 
-Top-k inside the kernel uses k rounds of (min, argmin, mask) — k is small
-(<=32) and the VPU eats the (Q, BN) compares; no sort network needed.
+Top-k inside the kernel is k rounds of (min, first-position, mask) over the
+running (Q, k) list and the fresh (Q, BN) tile together — k is small (<=32)
+and the VPU eats the compares; no sort network, no in-kernel gather (block
+ids are contiguous, so a tile position *is* an id) and no lane-unaligned
+concatenate, none of which Mosaic lowers. Ties prefer the running list,
+then the lower tile position: the lower id wins, as in ``lax.top_k``.
+Endpoints travel as 2-D ``(1, N)``/``(Q, 1)`` tiles (see
+:mod:`repro.kernels.pairwise_l2`).
 """
 from __future__ import annotations
 
@@ -20,23 +26,17 @@ from jax.experimental import pallas as pl
 
 from repro.core import intervals as iv
 
+from .pairwise_l2 import endpoint_tiles
+
 NO_EDGE = -1
 DEFAULT_BN = 1024
 
 
-def _extract_topk(dist, ids, k: int):
-    """k rounds of min-extraction. dist: (Q, M) fp32; ids: (Q, M) int32."""
-    Q = dist.shape[0]
-    out_d = []
-    out_i = []
-    for _ in range(k):
-        m = jnp.min(dist, axis=1)                      # (Q,)
-        am = jnp.argmin(dist, axis=1)                  # (Q,)
-        out_d.append(m)
-        out_i.append(jnp.take_along_axis(ids, am[:, None], 1)[:, 0])
-        dist = jnp.where(jnp.arange(dist.shape[1])[None, :] == am[:, None],
-                         jnp.inf, dist)
-    return jnp.stack(out_d, 1), jnp.stack(out_i, 1)    # (Q, k)
+def _first_min(x, pos, width: int):
+    """Row minimum of ``x`` and the first position holding it, as (Q, 1)."""
+    m = jnp.min(x, axis=1, keepdims=True)
+    at = jnp.min(jnp.where(x == m, pos, width), axis=1, keepdims=True)
+    return m, at
 
 
 def _kernel(q_ref, c_ref, lo_ref, hi_ref, ql_ref, qh_ref,
@@ -50,23 +50,38 @@ def _kernel(q_ref, c_ref, lo_ref, hi_ref, ql_ref, qh_ref,
 
     q = q_ref[...].astype(jnp.float32)                 # (Q, d)
     c = c_ref[...].astype(jnp.float32)                 # (BN, d)
+    hi_prec = jax.lax.Precision.HIGHEST
     qn = jnp.sum(q * q, axis=1, keepdims=True)
-    cn = jnp.sum(c * c, axis=1)
+    cn = jax.lax.dot_general(jnp.ones((1, c.shape[1]), jnp.float32), c * c,
+                             (((1,), (1,)), ((), ())), precision=hi_prec,
+                             preferred_element_type=jnp.float32)   # (1, BN)
     dist = qn - 2.0 * jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) + cn[None, :]
-    sel = iv.eval_predicate(mask, lo_ref[...][None, :], hi_ref[...][None, :],
-                            ql_ref[...][:, None], qh_ref[...][:, None])
+        q, c, (((1,), (1,)), ((), ())), precision=hi_prec,
+        preferred_element_type=jnp.float32) + cn
+    sel = iv.eval_predicate(mask, lo_ref[...], hi_ref[...],
+                            ql_ref[...], qh_ref[...])
     dist = jnp.where(sel, dist, jnp.inf)
-    gids = (step * bn + jnp.arange(bn, dtype=jnp.int32))[None, :]
-    gids = jnp.broadcast_to(gids, dist.shape)
 
-    new_d, new_i = _extract_topk(dist, gids, k)        # (Q, k)
-    cat_d = jnp.concatenate([outd_ref[...], new_d], axis=1)
-    cat_i = jnp.concatenate([outi_ref[...], new_i], axis=1)
-    merged_d, merged_i = _extract_topk(cat_d, cat_i, k)
-    outd_ref[...] = merged_d
-    outi_ref[...] = jnp.where(jnp.isfinite(merged_d), merged_i, NO_EDGE)
+    run_d, run_i = outd_ref[...], outi_ref[...]        # (Q, k)
+    Q = dist.shape[0]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (Q, bn), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, k), 1)
+    new_d = jnp.full((Q, k), jnp.inf, jnp.float32)
+    new_i = jnp.full((Q, k), NO_EDGE, jnp.int32)
+    for r in range(k):
+        m_run, at_run = _first_min(run_d, col, k)
+        m_blk, at_blk = _first_min(dist, pos, bn)
+        from_run = m_run <= m_blk
+        id_run = jnp.sum(jnp.where(col == at_run, run_i, 0), axis=1,
+                         keepdims=True)
+        pick_d = jnp.where(from_run, m_run, m_blk)
+        pick_i = jnp.where(from_run, id_run, step * bn + at_blk)
+        new_d = jnp.where(col == r, pick_d, new_d)
+        new_i = jnp.where(col == r, pick_i, new_i)
+        run_d = jnp.where(from_run & (col == at_run), jnp.inf, run_d)
+        dist = jnp.where(~from_run & (pos == at_blk), jnp.inf, dist)
+    outd_ref[...] = new_d
+    outi_ref[...] = jnp.where(jnp.isfinite(new_d), new_i, NO_EDGE)
 
 
 @functools.partial(jax.jit, static_argnames=("mask", "k", "bn", "interpret"))
@@ -75,12 +90,10 @@ def fused_topk_l2(queries, corpus, lo, hi, ql, qh, mask: int, k: int = 10,
     """(Q, d) x (N, d) -> exact filtered ((Q, k) ids, (Q, k) sq-distances)."""
     Q, d = queries.shape
     N = corpus.shape[0]
-    bn = min(bn, max(128, N))
+    bn = min(bn, -(-N // 128) * 128)
     Np = -(-N // bn) * bn
     cpad = jnp.pad(corpus, ((0, Np - N), (0, 0)))
-    # NaN endpoints fail every RR comparison -> padded rows never qualify
-    lop = jnp.pad(lo.astype(jnp.float32), (0, Np - N), constant_values=jnp.nan)
-    hip = jnp.pad(hi.astype(jnp.float32), (0, Np - N), constant_values=jnp.nan)
+    lop, hip, qlc, qhc = endpoint_tiles(lo, hi, ql, qh, Q, Np)
 
     outd, outi = pl.pallas_call(
         functools.partial(_kernel, mask=mask, k=k, bn=bn),
@@ -88,10 +101,10 @@ def fused_topk_l2(queries, corpus, lo, hi, ql, qh, mask: int, k: int = 10,
         in_specs=[
             pl.BlockSpec((Q, d), lambda i: (0, 0)),
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((Q,), lambda i: (0,)),
-            pl.BlockSpec((Q,), lambda i: (0,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((Q, 1), lambda i: (0, 0)),
+            pl.BlockSpec((Q, 1), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((Q, k), lambda i: (0, 0)),   # all steps alias block 0
@@ -100,5 +113,5 @@ def fused_topk_l2(queries, corpus, lo, hi, ql, qh, mask: int, k: int = 10,
         out_shape=[jax.ShapeDtypeStruct((Q, k), jnp.float32),
                    jax.ShapeDtypeStruct((Q, k), jnp.int32)],
         interpret=interpret,
-    )(queries, cpad, lop, hip, ql.astype(jnp.float32), qh.astype(jnp.float32))
+    )(queries, cpad, lop, hip, qlc, qhc)
     return outi, outd

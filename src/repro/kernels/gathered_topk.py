@@ -10,8 +10,10 @@ running-accumulator design: the merge is L rounds of (min, argmin, mask) on
 the VPU, which matches ``jax.lax.top_k``'s first-index tie-breaking exactly.
 
 The corpus table is presented to every grid step whole (the gather indices
-are per-query dynamic), so the TPU path assumes the table fits VMEM; the
-CPU/test path runs in interpret mode where the gather is a plain jnp take.
+are per-query dynamic), so it must fit VMEM, which a real corpus does not:
+on a TPU, :class:`repro.core.QueryEngine` refuses the graph route with
+``use_kernel=True`` instead of calling this kernel. The CPU/test path runs
+in interpret mode where the gather is a plain jnp take.
 Inputs follow the search loop's conventions: ``avail`` marks candidates that
 are structurally valid, unvisited, and first-occurrence (the loop computes
 this against its packed visited bitmap); ids may be ``NO_EDGE`` where not

@@ -6,8 +6,9 @@ on CPU — bitwise the same program structure, used by tests/benchmarks to
 validate against the :mod:`repro.kernels.ref` oracles.
 
 When a request trace is active (``repro.obs``), each entry point records a
-``kernel:<name>`` span annotated with achieved memory bandwidth vs the TPU
-v5e HBM peak (:func:`repro.obs.profile.bandwidth_annotation`). The traced
+``kernel:<name>`` span annotated with achieved memory bandwidth and, on a
+TPU, its share of that chip's HBM peak looked up by ``device_kind``
+(:func:`repro.obs.profile.bandwidth_annotation`). The traced
 path blocks on the result so the span measures the kernel, not the dispatch;
 with tracing off the wrappers stay fully async and add no work.
 
@@ -38,6 +39,12 @@ from . import ref
 @functools.cache
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+@functools.cache
+def _device_kind():
+    """The chip whose peaks kernel spans are shares of (None off-TPU)."""
+    return None if _interpret() else jax.devices()[0].device_kind
 
 
 def _nbytes(*arrays) -> int:
@@ -79,7 +86,8 @@ def _run_traced(name: str, nbytes: int, thunk):
     with obs.span(f"kernel:{name}") as sp:
         t0 = time.perf_counter()
         out = jax.block_until_ready(thunk())
-        ann = bandwidth_annotation(nbytes, time.perf_counter() - t0)
+        ann = bandwidth_annotation(nbytes, time.perf_counter() - t0,
+                                   _device_kind())
         for key, v in ann.items():
             sp.set(key, v)
     return out

@@ -15,10 +15,11 @@ candidates. The only approximation beyond storage quantization is the
 query-side rounding of ``w / alpha``; both are absorbed by the engine's
 exact float32 re-rank of the top ``rerank_k`` candidates.
 
-Block shapes follow the float32 kernel (the repo's kernels are exercised in
-interpret mode on this container); on a real TPU the int8 operands want the
-(32, 128) minimum tile, which the default (128, 256) blocks satisfy on the
-N axis whenever ``d`` is a lane multiple.
+Block shapes and the 2-D endpoint layout follow the float32 kernel
+(:func:`repro.kernels.pairwise_l2.block_sizes` / ``endpoint_tiles``); the
+per-query terms ride as ``(Q, 1)`` columns and ``sq_norm`` as a ``(1, N)``
+row. On a real TPU the int8 operands want the (32, 128) minimum tile, so the
+query block is a multiple of 32.
 """
 from __future__ import annotations
 
@@ -30,6 +31,7 @@ from jax.experimental import pallas as pl
 
 from repro.core import intervals as iv
 
+from .pairwise_l2 import block_sizes, endpoint_tiles
 from .ref import quantize_query_weights_ref
 
 DEFAULT_BQ = 128
@@ -43,11 +45,11 @@ def _kernel(wq_ref, c_ref, alpha_ref, cq_ref, sqn_ref, lo_ref, hi_ref,
     # MXU int8 path: (BQ, d) x (d, BN) with int32 accumulation
     acc = jax.lax.dot_general(wq, c, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.int32)
-    dist = (cq_ref[...][:, None]
-            - 2.0 * alpha_ref[...][:, None] * acc.astype(jnp.float32)
-            + sqn_ref[...][None, :])
-    sel = iv.eval_predicate(mask, lo_ref[...][None, :], hi_ref[...][None, :],
-                            ql_ref[...][:, None], qh_ref[...][:, None])
+    dist = (cq_ref[...]                         # (BQ, 1)
+            - 2.0 * alpha_ref[...] * acc.astype(jnp.float32)
+            + sqn_ref[...])                     # (1, BN)
+    sel = iv.eval_predicate(mask, lo_ref[...], hi_ref[...],
+                            ql_ref[...], qh_ref[...])
     out_ref[...] = jnp.where(sel, dist, jnp.inf)
 
 
@@ -61,21 +63,15 @@ def pairwise_l2_int8(queries, codes, scale, offset, sq_norm, lo, hi, ql, qh,
     Q, d = queries.shape
     N = codes.shape[0]
     wq, alpha, cq = quantize_query_weights_ref(queries, scale, offset)
-    bq = min(bq, max(8, Q))
-    bn = min(bn, max(128, N))
-    Qp = -(-Q // bq) * bq
-    Np = -(-N // bn) * bn
+    bq, bn, Qp, Np = block_sizes(-(-Q // 32) * 32, N, bq, bn)
     wqp = jnp.pad(wq, ((0, Qp - Q), (0, 0)))
     cpad = jnp.pad(codes, ((0, Np - N), (0, 0)))
     # alpha pads to 1 (a 0 divisor never happens; value is irrelevant —
     # padded rows/cols are predicate-masked via NaN endpoints below)
-    alphap = jnp.pad(alpha, (0, Qp - Q), constant_values=1.0)
-    cqp = jnp.pad(cq, (0, Qp - Q))
-    sqnp = jnp.pad(sq_norm.astype(jnp.float32), (0, Np - N))
-    lop = jnp.pad(lo.astype(jnp.float32), (0, Np - N), constant_values=jnp.nan)
-    hip = jnp.pad(hi.astype(jnp.float32), (0, Np - N), constant_values=jnp.nan)
-    qlp = jnp.pad(ql.astype(jnp.float32), (0, Qp - Q), constant_values=jnp.nan)
-    qhp = jnp.pad(qh.astype(jnp.float32), (0, Qp - Q), constant_values=jnp.nan)
+    alphap = jnp.pad(alpha, (0, Qp - Q), constant_values=1.0)[:, None]
+    cqp = jnp.pad(cq, (0, Qp - Q))[:, None]
+    sqnp = jnp.pad(sq_norm.astype(jnp.float32), (0, Np - N))[None, :]
+    lop, hip, qlp, qhp = endpoint_tiles(lo, hi, ql, qh, Qp, Np)
 
     grid = (Qp // bq, Np // bn)
     out = pl.pallas_call(
@@ -84,13 +80,13 @@ def pairwise_l2_int8(queries, codes, scale, offset, sq_norm, lo, hi, ql, qh,
         in_specs=[
             pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
-            pl.BlockSpec((bq,), lambda i, j: (i,)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Qp, Np), jnp.float32),

@@ -152,8 +152,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, artifact_dir: str,
             t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         print(compiled.memory_analysis())
-        from .compat import cost_analysis_dict
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
         hlo = compiled.as_text()
         colls, cwire, ccounts = collective_bytes(

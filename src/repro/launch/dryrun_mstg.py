@@ -26,7 +26,7 @@ from repro.core.hnsw import NO_EDGE
 from repro.distributed.topk import global_topk_merge, tournament_topk_merge
 from repro.launch.dryrun import ARTIFACT_DIR, collective_bytes
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import HBM_BW, LINK_BW, PEAK_FLOPS
+from repro.obs.profile import V5E, peaks
 
 # production serving shape: 1M corpus x 1024-query batch, d=128 (SIFT-like)
 N_CORPUS = 1 << 20
@@ -36,7 +36,6 @@ K = 10
 
 
 def build_step(mesh, merge: str, mask: int = ANY_OVERLAP, k: int = K):
-    from jax.experimental.shard_map import shard_map
     corpus_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     D = int(np.prod([mesh.shape[a] for a in corpus_axes]))
     nloc = N_CORPUS // D
@@ -48,11 +47,11 @@ def build_step(mesh, merge: str, mask: int = ANY_OVERLAP, k: int = K):
     # corpus over (pod, data); queries over 'model' — every device does
     # (Q/model) x (N/(pod*data)) distance work, the full-mesh decomposition
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(corpus_axes, None), P(corpus_axes), P(corpus_axes),
                   P("model", None), P("model"), P("model")),
         out_specs=(P("model", None), P("model", None)),
-        check_rep=False)
+        check_vma=False)
     def run(c, l, h, q, a, b):
         ids, d = flat_search(c, l, h, q, a, b, mask=mask, k=k)
         idx = jax.lax.axis_index(corpus_axes[0])
@@ -86,18 +85,17 @@ def build_step_v2(mesh, mask: int = ANY_OVERLAP, k: int = K):
     replicated, blocked fused top-k (no HBM distance matrix), hierarchical
     tournament merge. Arithmetic intensity per corpus byte rises from
     2·(Q/model) to 2·Q — past the v5e knee."""
-    from jax.experimental.shard_map import shard_map
     from repro.core.flat import flat_search_blocked
     axes = tuple(a for a in ("pod", "data", "model") if a in mesh.shape)
     Dall = int(np.prod([mesh.shape[a] for a in axes]))
     nloc = N_CORPUS // Dall
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes, None), P(axes), P(axes),
                   P(None, None), P(None), P(None)),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False)
+        check_vma=False)
     def run(c, l, h, q, a, b):
         ids, d = flat_search_blocked(c, l, h, q, a, b, mask=mask, k=k)
         idx = jnp.zeros((), jnp.int32)
@@ -133,8 +131,7 @@ def run_cell(mesh_kind: str, merge: str, artifact_dir: str, force=False):
     t0 = time.time()
     with mesh:
         compiled = jax.jit(fn).lower(*args).compile()
-    from .compat import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     colls, wire, counts = collective_bytes(compiled.as_text(), ndev)
     flops = float(ca.get("flops", 0))
@@ -148,9 +145,9 @@ def run_cell(mesh_kind: str, merge: str, artifact_dir: str, force=False):
                    "argument_bytes": getattr(mem, "argument_size_in_bytes", None)},
         "collective_bytes": colls, "collective_wire_bytes": wire,
         "collective_counts": counts,
-        "terms": {"compute_s": flops / PEAK_FLOPS,
-                  "memory_hlo_s": nbytes / HBM_BW,
-                  "collective_s": sum(colls.values()) / LINK_BW},
+        "terms": {"compute_s": flops / peaks(V5E)["flops"],
+                  "memory_hlo_s": nbytes / peaks(V5E)["hbm_bw"],
+                  "collective_s": sum(colls.values()) / peaks(V5E)["link_bw"]},
         # model flops per device: Q_loc x N_loc masked distances
         "model_flops_per_device": (
             N_QUERIES * (N_CORPUS / ndev) * 2 * DIM if merge == "fullmesh_v2"

@@ -5,19 +5,12 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import)."""
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType / make_mesh(axis_types=...) appeared after jax 0.4.x
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with explicit Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -35,7 +35,7 @@ from repro.models.transformer import LM
 
 # chip constants live in repro.obs.profile so kernel trace spans and this
 # analytic model agree on the same peaks; re-exported here for callers.
-from repro.obs.profile import HBM_BW, LINK_BW, PEAK_FLOPS
+from repro.obs.profile import V5E, peaks
 
 ROOF_DIR = os.environ.get("ROOFLINE_ARTIFACTS",
                           os.path.join(os.path.dirname(ARTIFACT_DIR), "roofline"))
@@ -57,8 +57,7 @@ def _measure(cfg, shape_name, mesh, repeats):
                            out_shardings=bundle.out_shardings,
                            donate_argnums=bundle.donate
                            ).lower(*bundle.args).compile()
-    from .compat import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     ndev = int(np.prod(list(mesh.shape.values())))
     colls, wire, _ = collective_bytes(compiled.as_text(), ndev)
     return {"flops": float(ca.get("flops", 0.0)),
@@ -192,13 +191,14 @@ def analyze_cell(arch: str, shape_name: str, artifact_dir: str,
             corr[key] += (R[k] - 1) * u[key]
 
     mf = model_flops(cfg, lm, shape, devices)
+    pk = peaks(V5E)
     terms = {
-        "compute_s": corr["flops"] / PEAK_FLOPS,
-        "memory_hlo_s": corr["bytes"] / HBM_BW,      # unfused upper bound
+        "compute_s": corr["flops"] / pk["flops"],
+        "memory_hlo_s": corr["bytes"] / pk["hbm_bw"],  # unfused upper bound
         "memory_s": analytic_memory_bytes(cfg, lm, shape,
-                                          dict(mesh.shape)) / HBM_BW,
-        "collective_s": corr["coll"] / LINK_BW,
-        "collective_wire_s": corr["wire"] / LINK_BW,
+                                          dict(mesh.shape)) / pk["hbm_bw"],
+        "collective_s": corr["coll"] / pk["link_bw"],
+        "collective_wire_s": corr["wire"] / pk["link_bw"],
     }
     core = {k: terms[k] for k in ("compute_s", "memory_s", "collective_s")}
     dominant = max(core, key=core.get)

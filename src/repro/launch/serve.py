@@ -31,6 +31,7 @@ from repro.configs import get_smoke_config
 from repro.core import (IndexSpec, MSTGIndex, Overlaps, QueryContained,
                         QueryEngine)
 from repro.data import make_range_dataset, make_queries
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import LM
 from repro.serving import RetrievalServer, ServeEngine
 
@@ -60,6 +61,7 @@ def main():
                          "Prometheus text at /metrics, the typed JSON "
                          "snapshot at /metrics.json (0 = ephemeral port)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.shards and args.streaming:
         ap.error("--shards and --streaming are mutually exclusive (shard a "
                  "SegmentedIndex via ShardedDeployment.from_segmented)")

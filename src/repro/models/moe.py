@@ -25,7 +25,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .common import act_fn
 from .params import meta
@@ -154,13 +153,13 @@ def moe_apply(p, x, *, cfg, mesh: Optional[Mesh], batch_axes,
                                 and D % mesh.shape["data"] == 0) else None)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(batch_axes, None, None), P(None, None), P(None),
                       P("model", "data" if fsdp_axis else None, None),
                       P("model", "data" if fsdp_axis else None, None),
                       P("model", None, "data" if fsdp_axis else None)),
             out_specs=(P(batch_axes, None, None), P()),
-            check_rep=False)
+            check_vma=False)
         def run(x_blk, router_w, bias, wg, wu, wd):
             Bl, Sl, Dl = x_blk.shape
             e_lo = jax.lax.axis_index("model") * E_loc
@@ -191,12 +190,12 @@ def _moe_full_ep(p, x, *, cfg, mesh, ep_axes, capacity_factor):
     capacity = max(int(np.ceil(T * k / E * capacity_factor)), 4)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, None, None), P(None, None), P(None),
                   P(ep_axes, None, None), P(ep_axes, None, None),
                   P(ep_axes, None, None)),
         out_specs=(P(None, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     def run(x_rep, router_w, bias, wg, wu, wd):
         e_lo = jnp.zeros((), jnp.int32)
         stride = E_loc

@@ -21,8 +21,8 @@ distributed (and the benchmark drivers):
 * **logs + profiling** (:mod:`repro.obs.log`, :mod:`repro.obs.profile`) —
   rate-limited structured progress logging (:func:`get_logger`), an opt-in
   ``jax.profiler`` capture wrapper (:func:`profiler_capture`), and the
-  roofline peak constants + :func:`bandwidth_annotation` used to annotate
-  kernel spans with achieved-vs-peak bandwidth.
+  per-``device_kind`` peak table (:data:`PEAKS`) + :func:`bandwidth_annotation`
+  used to annotate kernel spans with achieved-vs-peak bandwidth.
 """
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, REGISTRY,
                       StreamingHistogram, get_registry, start_metrics_server)
@@ -30,7 +30,7 @@ from .trace import (NULL_SPAN, Span, Trace, Tracer, active_tracer,
                     begin_request_trace, capture, end_request_trace, span,
                     tracing)
 from .log import StructuredLogger, get_logger
-from .profile import (HBM_BW, LINK_BW, PEAK_FLOPS, bandwidth_annotation,
+from .profile import (PEAKS, V5E, bandwidth_annotation, peaks,
                       profiler_capture)
 
 __all__ = [
@@ -43,6 +43,6 @@ __all__ = [
     # logs
     "StructuredLogger", "get_logger",
     # profiling
-    "HBM_BW", "LINK_BW", "PEAK_FLOPS", "bandwidth_annotation",
+    "PEAKS", "V5E", "bandwidth_annotation", "peaks",
     "profiler_capture",
 ]

@@ -9,41 +9,60 @@ Two pieces:
   block still runs and the context records ``.error`` instead of raising —
   profiling must never take down a serving process.
 
-* roofline constants + :func:`bandwidth_annotation` — the hardware peaks
-  that ``repro.launch.roofline`` prices HLO costs against (TPU v5e: bf16
-  FLOPs, HBM and ICI link bandwidth) now live here so kernel-level spans
-  and the roofline driver agree on one set of numbers.
-  ``bandwidth_annotation(nbytes, seconds)`` turns a measured kernel span
-  into achieved GB/s and the fraction of peak — attached to kernel spans by
-  ``repro.kernels.ops`` when tracing is on, and usable standalone from
-  benchmark drivers.
+* :data:`PEAKS` + :func:`peaks` + :func:`bandwidth_annotation` — published
+  per-chip peaks keyed by JAX's ``device_kind`` (bf16 FLOP/s, HBM and ICI
+  link bandwidth), shared by ``repro.launch.roofline`` and the kernel spans.
+  A device missing from the table is an error, never a default.
+  ``bandwidth_annotation(nbytes, seconds, device_kind)`` turns a measured
+  kernel span into achieved GB/s and, for a device with a known peak, the
+  fraction of its HBM peak — attached to kernel spans by
+  ``repro.kernels.ops`` when tracing is on.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional
 
-__all__ = ["PEAK_FLOPS", "HBM_BW", "LINK_BW", "bandwidth_annotation",
+__all__ = ["PEAKS", "V5E", "bandwidth_annotation", "peaks",
            "profiler_capture"]
 
-# TPU v5e single-chip peaks (the roofline reference point; CPU interpret-mode
-# numbers annotated against these document *distance from target hardware*,
-# not CPU efficiency).
-PEAK_FLOPS = 197e12     # bf16 FLOP/s per chip
-HBM_BW = 819e9          # HBM bytes/s per chip
-LINK_BW = 50e9          # bytes/s per ICI link
+V5E = "TPU v5 lite"     # jax's device_kind of a TPU v5e chip
+
+# Published single-chip peaks by device_kind. Source for the v5e row: Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600
+# Gbit/s of chip-to-chip interconnect over 4 ICI links (50 GB/s each).
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The :data:`PEAKS` row of ``device_kind``; an unknown device raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; add a sourced row to "
+                       f"repro.obs.profile.PEAKS") from None
 
 
 def bandwidth_annotation(nbytes: float, seconds: float,
-                         peak_bw: float = HBM_BW) -> Dict[str, float]:
-    """Achieved memory bandwidth of a measured region vs a peak.
+                         device_kind: Optional[str] = None
+                         ) -> Dict[str, float]:
+    """Achieved memory bandwidth of a measured region.
 
-    Returns ``{"bytes", "gb_per_s", "frac_of_peak"}`` — the dict a kernel
-    span attaches via ``sp.set``. ``seconds <= 0`` reports 0 bandwidth
+    Returns ``{"bytes", "gb_per_s"}`` plus ``"frac_of_peak"`` (of the HBM
+    peak of ``device_kind``, which must be in :data:`PEAKS`) when a device
+    kind is given — the dict a kernel span attaches via ``sp.set``. Off the
+    accelerator pass ``None``: a host-clock rate of interpret-mode code has
+    no device peak to be a share of. ``seconds <= 0`` reports 0 bandwidth
     rather than dividing by zero (a clock can quantize to 0 on tiny
     kernels)."""
     gbs = (nbytes / seconds / 1e9) if seconds > 0 else 0.0
-    return {"bytes": float(nbytes), "gb_per_s": round(gbs, 3),
-            "frac_of_peak": round(gbs * 1e9 / peak_bw, 6)}
+    out = {"bytes": float(nbytes), "gb_per_s": round(gbs, 3)}
+    if device_kind is not None:
+        out["frac_of_peak"] = round(gbs * 1e9 / peaks(device_kind)["hbm_bw"],
+                                    6)
+    return out
 
 
 class profiler_capture:

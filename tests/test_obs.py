@@ -348,11 +348,17 @@ def test_progress_rate_limit():
 
 
 def test_bandwidth_annotation():
-    from repro.obs.profile import HBM_BW, bandwidth_annotation
-    ann = bandwidth_annotation(HBM_BW, 1.0)      # one peak-second of bytes
+    from repro.obs.profile import V5E, bandwidth_annotation, peaks
+    hbm = peaks(V5E)["hbm_bw"]
+    ann = bandwidth_annotation(hbm, 1.0, V5E)    # one peak-second of bytes
     assert ann["frac_of_peak"] == pytest.approx(1.0)
-    assert ann["gb_per_s"] == pytest.approx(HBM_BW / 1e9)
+    assert ann["gb_per_s"] == pytest.approx(hbm / 1e9)
     assert bandwidth_annotation(1024, 0.0)["gb_per_s"] == 0.0
+    # off the accelerator there is no peak to take a share of
+    assert "frac_of_peak" not in bandwidth_annotation(hbm, 1.0)
+    # an unknown chip is an error, never a v5e default
+    with pytest.raises(KeyError, match="no published peaks"):
+        bandwidth_annotation(hbm, 1.0, "TPU v99")
 
 
 def test_kernel_span_records_bandwidth(small_ds):
@@ -369,4 +375,6 @@ def test_kernel_span_records_bandwidth(small_ds):
     np.testing.assert_allclose(traced, ref)
     sp = trace.roots[0]
     assert sp.name == "kernel:gathered_l2"
-    assert {"bytes", "gb_per_s", "frac_of_peak"} <= set(sp.args)
+    assert {"bytes", "gb_per_s"} <= set(sp.args)
+    # interpret mode on the CPU: a host-clock rate, no device peak share
+    assert "frac_of_peak" not in sp.args

@@ -71,8 +71,7 @@ def test_dryrun_pipeline_small_mesh():
             c = jax.jit(b.fn, in_shardings=b.in_shardings,
                         out_shardings=b.out_shardings,
                         donate_argnums=b.donate).lower(*b.args).compile()
-        from repro.launch.compat import cost_analysis_dict
-        ca = cost_analysis_dict(c)
+        ca = c.cost_analysis()
         assert ca["flops"] > 0
         colls, wire, counts = collective_bytes(c.as_text(), 8)
         assert sum(counts.values()) > 0, "expected collectives on a 3-axis mesh"

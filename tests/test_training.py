@@ -45,7 +45,6 @@ def test_quantize_roundtrip_error_bounded():
 def test_compressed_sync_single_shard_with_error_feedback():
     """On a 1-device axis the compressed mean must equal plain quantization,
     and error feedback must cancel bias over repeated steps."""
-    import jax.experimental.shard_map as shm
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(0, 1, (64,))
@@ -56,8 +55,8 @@ def test_compressed_sync_single_shard_with_error_feedback():
         out, nr = compressed_grad_sync({"w": gw}, "data", {"w": rw})
         return out["w"], nr["w"]
 
-    f = shm.shard_map(run, mesh=mesh, in_specs=(P(), P()),
-                      out_specs=(P(), P()), check_rep=False)
+    f = jax.shard_map(run, mesh=mesh, in_specs=(P(), P()),
+                      out_specs=(P(), P()), check_vma=False)
     acc = jnp.zeros_like(g["w"])
     r = res["w"]
     for _ in range(16):
