@@ -11,8 +11,8 @@ Walks the ``repro.obs`` surface end to end on a small MSTG index:
    the merge schedule;
 3. engine-level sampling — ``EngineConfig(trace_sample=0.25)`` traces every
    4th request with no caller opt-in;
-4. scoped capture + kernel bandwidth — ``with obs.capture()`` traces any
-   block; kernel spans annotate achieved GB/s vs the TPU v5e HBM peak;
+4. scoped capture + kernel spans — ``with obs.capture()`` traces any
+   block; kernel spans carry the bytes their kernel streams;
 5. the metrics registry — counters/histograms every subsystem records into,
    snapshot + Prometheus text (``repro.launch.serve --metrics-port`` serves
    the same over HTTP).
@@ -64,8 +64,8 @@ def main():
     traced = [sampled.execute(req_off).trace is not None for _ in range(8)]
     print(f"\ntrace_sample=0.25 over 8 requests -> traced={traced}")
 
-    # 4. scoped capture around arbitrary code; kernel spans carry achieved
-    # bandwidth vs the HBM peak (repro.obs.profile)
+    # 4. scoped capture around arbitrary code; kernel spans carry the bytes
+    # their kernel streams by its byte model
     from repro.kernels import ops
     import jax.numpy as jnp
     q = jnp.asarray(ds.queries[:4])

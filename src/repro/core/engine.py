@@ -64,6 +64,7 @@ from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro import obs
@@ -84,6 +85,8 @@ ROUTE_GRAPH = "graph"
 ROUTE_PRUNED = "pruned"
 ROUTE_FLAT = "flat"
 _ROUTES = (ROUTE_AUTO, ROUTE_GRAPH, ROUTE_PRUNED, ROUTE_FLAT)
+# kinds of engine_pruned_rows_total, in the column order of _scan_rows
+_ROW_KINDS = ("needed", "to_longest", "scanned")
 
 
 def _next_pow2(x: int) -> int:
@@ -122,6 +125,24 @@ def reset_deprecation_warnings() -> None:
 def _empty_result(Q: int, k: int) -> Tuple[np.ndarray, np.ndarray]:
     return (np.full((Q, k), NO_EDGE, np.int32),
             np.full((Q, k), np.inf, np.float32))
+
+
+def _scan_rows(scans: List[tuple]) -> np.ndarray:
+    """Candidate rows of each pruned slot scan, (slots, 3) int64 in
+    :data:`_ROW_KINDS` order: *needed*, the sum of the queries' candidate
+    prefixes; *to_longest*, what a loop stopped at the batch's longest
+    prefix would run (Qp x that prefix in whole blocks); *scanned*, what the
+    loop runs (Qp x max_blocks x block). ``scans`` holds ``(total,
+    max_blocks, block)`` per slot, ``total`` the scan's (Qp,) device array of
+    prefix lengths; every slot's totals come back in one fetch."""
+    totals = jax.device_get([t for t, _, _ in scans])
+    out = np.zeros((len(scans), len(_ROW_KINDS)), np.int64)
+    for i, (tot, (_, max_blocks, block)) in enumerate(zip(totals, scans)):
+        tot = np.asarray(tot, np.int64)
+        longest = -(-int(tot.max(initial=0)) // block)
+        out[i] = (tot.sum(), tot.size * longest * block,
+                  tot.size * max_blocks * block)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -386,6 +407,13 @@ class QueryEngine:
                             labels=("outcome",))
         self._m_sel_hit = sel_c.labels(outcome="hit")
         self._m_sel_miss = sel_c.labels(outcome="miss")
+        rows_c = reg.counter("engine_pruned_rows_total",
+                             "Candidate rows of the pruned route's slot "
+                             "scans: needed by the queries, to_longest (a "
+                             "scan stopped at the batch's longest prefix), "
+                             "scanned (what the scan loop runs)",
+                             labels=("kind",))
+        self._m_rows = tuple(rows_c.labels(kind=kind) for kind in _ROW_KINDS)
 
     # ---- device staging (lazy, cached per variant) ----
     @property
@@ -625,12 +653,13 @@ class QueryEngine:
                                                             ROUTE_PRUNED)
                      else [])
             psp.set("slots", len(slots))
-        with obs.span(route):
+        scans = None
+        with obs.span(route) as rsp:
             if route == ROUTE_FLAT:
                 ids, d = self._run_flat(queries, qlo, qhi, mask, k)
             elif route == ROUTE_PRUNED:
-                ids, d = self._run_pruned(queries, qlo, qhi, mask, k,
-                                          slots=slots)
+                ids, d, scans = self._run_pruned(queries, qlo, qhi, mask, k,
+                                                 slots=slots)
             elif route == ROUTE_GRAPH:
                 ids, d = self._run_graph(queries, qlo, qhi, mask, k,
                                          request.ef, request.max_steps,
@@ -638,7 +667,11 @@ class QueryEngine:
                                          chunk=request.chunk)
             else:
                 raise ValueError(f"unknown route {route!r}")
-            ids, d = np.asarray(ids[:Q]), np.asarray(d[:Q])
+            # the host waits here until the answers are on it
+            with obs.span("fetch"):
+                ids, d = np.asarray(ids[:Q]), np.asarray(d[:Q])
+            if scans is not None:
+                self._count_scans(scans, rsp)
         report = RouteReport(route=route, requested=requested,
                              est_selectivity=est, slot_count=len(slots),
                              variants=tuple(s.variant for s in slots),
@@ -663,9 +696,14 @@ class QueryEngine:
         if Q == 0:
             return _empty_result(0, k)
         self.route_counts[ROUTE_PRUNED] = self.route_counts.get(ROUTE_PRUNED, 0) + 1
-        ids, d = self._run_pruned(queries, qlo, qhi, mask, k, block=block,
-                                  max_candidates=max_candidates)
-        return np.asarray(ids[:Q]), np.asarray(d[:Q])
+        with obs.span(ROUTE_PRUNED) as rsp:
+            ids, d, scans = self._run_pruned(queries, qlo, qhi, mask, k,
+                                             block=block,
+                                             max_candidates=max_candidates)
+            with obs.span("fetch"):
+                ids, d = np.asarray(ids[:Q]), np.asarray(d[:Q])
+            self._count_scans(scans, rsp)
+        return ids, d
 
     def search_flat(self, queries, qlo, qhi, mask, k=10):
         req = SearchRequest(queries, (qlo, qhi), mask, k=k, route=ROUTE_FLAT)
@@ -718,6 +756,18 @@ class QueryEngine:
         if _backend() == "tpu":
             return max(1, min(8, ef // 16))
         return 1
+
+    def _count_scans(self, scans: List[tuple], sp) -> None:
+        """Add the pruned route's candidate rows (:func:`_scan_rows`) to
+        ``engine_pruned_rows_total`` and, while tracing, to the route span
+        ``sp``. Called after the answers' fetch, so it adds no wait."""
+        rows = _scan_rows(scans).sum(axis=0)
+        for child, v in zip(self._m_rows, rows):
+            child.inc(float(v))
+        if obs.tracing():
+            needed, to_longest, scanned = (int(v) for v in rows)
+            sp.set("slots", len(scans)).set("rows_needed", needed)
+            sp.set("rows_scanned", scanned).set("rows_to_longest", to_longest)
 
     def _rerank_width(self, k: int, upper: Optional[int] = None) -> int:
         """Approximate candidates per query surviving to the exact re-rank:
@@ -792,11 +842,16 @@ class QueryEngine:
     def _run_pruned(self, queries, qlo, qhi, mask, k, block: int = 256,
                     max_candidates: Optional[int] = None,
                     slots: Optional[List[iv.PlanSlot]] = None):
+        """Dispatch one scan per non-empty plan slot and merge them. Returns
+        ``(ids, dists, scans)`` with the answers still on the device and
+        ``scans`` as :func:`_scan_rows` reads it; nothing here waits on the
+        device (bar the compressed tier's re-rank)."""
         if slots is None:
             slots = self.plan(mask, qlo, qhi)
         n = self.index.vectors.shape[0]
         queries_p, qlo_p, qhi_p = self._padded(queries, qlo, qhi)
-        slots = self._padded_slots(slots, queries_p.shape[0])
+        Qp = queries_p.shape[0]
+        slots = self._padded_slots(slots, Qp)
         qdev = jnp.asarray(queries_p)
         qlo_j = jnp.asarray(qlo_p, jnp.float32)
         qhi_j = jnp.asarray(qhi_p, jnp.float32)
@@ -804,6 +859,7 @@ class QueryEngine:
         # slot and through the merge, then re-rank exactly once at the end
         kq = k if self._store is None else self._rerank_width(k)
         res = None
+        scans = []
         for s in slots:
             fv = self.index.variants[s.variant]
             # exact candidate upper bound for this slot: objects with
@@ -819,21 +875,33 @@ class QueryEngine:
                 cap = min(n, _next_pow2(cap)) if cap else 0
             if cap == 0:
                 continue  # every query's task in this slot is empty
+            max_blocks = -(-cap // block)
+            # times the host's dispatch of the scan; its device time is the
+            # profiler's to measure
             with obs.span("slot") as ssp:
                 ssp.set("variant", s.variant).set("candidates", cap)
-                ids, d = _pruned_search_variant(
+                ssp.set("rows", Qp).set("max_blocks", max_blocks)
+                ssp.set("block", block)
+                ids, d, total = _pruned_search_variant(
                     self.pruned_dev(s.variant), self.lo, self.hi, qdev,
                     qlo_j, qhi_j, jnp.asarray(s.version, jnp.int32),
                     jnp.asarray(s.key_lo, jnp.int32), jnp.asarray(s.key_hi, jnp.int32),
                     pred_mask_bits=mask, k=kq, Kpad=fv.Kpad, block=block,
-                    max_blocks=-(-cap // block))
-            res = (ids, d) if res is None else merge_topk(res[0], res[1], ids,
-                                                          d, kq)
+                    max_blocks=max_blocks)
+            # start the totals' copy to the host now, so that it is there by
+            # the time the answers are: fetching them then costs no wait
+            total.copy_to_host_async()
+            scans.append((total, max_blocks, block))
+            if res is None:
+                res = (ids, d)
+            else:
+                with obs.span("merge"):     # times the dispatch, as "slot"
+                    res = merge_topk(res[0], res[1], ids, d, kq)
         if res is None:
-            return _empty_result(queries_p.shape[0], k)
+            return (*_empty_result(Qp, k), scans)
         if self._store is not None:
-            return self._rerank_exact(qdev, res[0], k)
-        return res
+            return (*self._rerank_exact(qdev, res[0], k), scans)
+        return (*res, scans)
 
     def _run_flat(self, queries, qlo, qhi, mask, k):
         queries_p, qlo_p, qhi_p = self._padded(queries, qlo, qhi)

@@ -110,7 +110,12 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
     """One variant's pruned scan: decomposition -> member prefixes -> blocked
     fused distance + running top-k. ``pred_mask_bits`` re-checks the exact
     predicate on gathered candidates (cheap; guards rank-boundary ties and
-    lets one variant serve any sub-mask of its plan)."""
+    lets one variant serve any sub-mask of its plan).
+
+    Returns ``(ids, dists, total)``: ``total`` is each query's (Q,) int32
+    candidate-prefix length, the rows its answer needs. The loop runs
+    ``max_blocks * block`` rows for every query; the answer is exact only
+    while ``total <= max_blocks * block``."""
     # quantized layouts carry "codes" (+ affine params) instead of a float32
     # "vectors" table; dict keys are static under jit, so this picks the
     # gather source at trace time with no runtime branch
@@ -194,7 +199,7 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
         return (( -neg, jnp.take_along_axis(cat_i, pos_k, 1))), None
 
     (top_d, top_i), _ = jax.lax.scan(body, (top_d, top_i), jnp.arange(max_blocks))
-    return top_i, top_d
+    return top_i, top_d, total
 
 
 # The host-facing exact-search API is QueryEngine (repro.core.engine) with

@@ -5,30 +5,28 @@ they run in ``interpret=True`` mode, which executes the kernel body in Python
 on CPU — bitwise the same program structure, used by tests/benchmarks to
 validate against the :mod:`repro.kernels.ref` oracles.
 
-When a request trace is active (``repro.obs``), each entry point records a
-``kernel:<name>`` span annotated with achieved memory bandwidth and, on a
-TPU, its share of that chip's HBM peak looked up by ``device_kind``
-(:func:`repro.obs.profile.bandwidth_annotation`). The traced
-path blocks on the result so the span measures the kernel, not the dispatch;
-with tracing off the wrappers stay fully async and add no work.
+While tracing is on (a ``repro.obs`` tracer installed or a
+``jax.profiler`` session recording), each entry point records a
+``kernel:<name>`` span around its dispatch, annotated with ``bytes``: the
+bytes the kernel streams by its byte model. The span does not wait for the
+kernel: the device time is the profiler's to measure, and a traced run
+dispatches exactly as an untraced one. Under ``jax.jit`` the span times the
+trace, once per compile.
 
-Bandwidth is annotated against *per-kernel byte models*, not a naive sum of
-input array sizes: the gathered kernels read ``Q*M`` candidate rows out of
-the table (not the whole table), and the compressed-scan kernels stream the
-int8/float16 code bytes (not a float32-equivalent) — so the achieved-GB/s
-roofline numbers stay honest across storage tiers. The models are exported
+The byte models are *per kernel*, not a naive sum of input array sizes:
+the gathered kernels read ``Q*M`` candidate rows out of the table (not the
+whole table), and the compressed-scan kernels stream the int8/float16 code
+bytes (not a float32-equivalent). They are exported
 (:func:`pairwise_stream_bytes`, :func:`gathered_stream_bytes`) for
 benchmarks that report side-by-side float32/int8 bandwidth.
 """
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 
 from repro import obs
-from repro.obs.profile import bandwidth_annotation
 
 from . import pairwise_l2 as _pw
 from . import pairwise_l2_int8 as _pw8
@@ -39,12 +37,6 @@ from . import ref
 @functools.cache
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-@functools.cache
-def _device_kind():
-    """The chip whose peaks kernel spans are shares of (None off-TPU)."""
-    return None if _interpret() else jax.devices()[0].device_kind
 
 
 def _nbytes(*arrays) -> int:
@@ -79,32 +71,21 @@ def gathered_stream_bytes(Q: int, M: int, L: int, d: int,
             + 2 * Q * L * (4 + 4 + 4))  # beam pool in + out (ids, d, exp)
 
 
-def _run_traced(name: str, nbytes: int, thunk):
-    """Run ``thunk`` inside a ``kernel:<name>`` span with an achieved-vs-peak
-    bandwidth annotation. Only entered when a tracer is active — the traced
-    path blocks on the result so the measured wall time bounds the kernel."""
-    with obs.span(f"kernel:{name}") as sp:
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(thunk())
-        ann = bandwidth_annotation(nbytes, time.perf_counter() - t0,
-                                   _device_kind())
-        for key, v in ann.items():
-            sp.set(key, v)
-    return out
+def _kernel_span(name: str, nbytes: int):
+    """The span around one kernel's dispatch, carrying the bytes it streams
+    by its byte model (:data:`repro.obs.NULL_SPAN` while tracing is off).
+    It does not wait for the kernel."""
+    return obs.span(name).set("bytes", int(nbytes))
 
 
 def pairwise_l2_masked(queries, corpus, lo, hi, ql, qh, mask: int,
                        bq: int = _pw.DEFAULT_BQ, bn: int = _pw.DEFAULT_BN):
-    thunk = lambda: _pw.pairwise_l2_masked(  # noqa: E731
-        queries, corpus, lo, hi, ql, qh, mask, bq=bq, bn=bn,
-        interpret=_interpret())
-    if not obs.tracing():
-        return thunk()
     Q, d = queries.shape
-    N = corpus.shape[0]
-    return _run_traced(
-        "pairwise_l2_masked",
-        pairwise_stream_bytes(Q, N, d, corpus.dtype.itemsize), thunk)
+    nbytes = pairwise_stream_bytes(Q, corpus.shape[0], d,
+                                   corpus.dtype.itemsize)
+    with _kernel_span("kernel:pairwise_l2_masked", nbytes):
+        return _pw.pairwise_l2_masked(queries, corpus, lo, hi, ql, qh, mask,
+                                      bq=bq, bn=bn, interpret=_interpret())
 
 
 def pairwise_l2_int8(queries, codes, scale, offset, sq_norm, lo, hi, ql, qh,
@@ -112,34 +93,28 @@ def pairwise_l2_int8(queries, codes, scale, offset, sq_norm, lo, hi, ql, qh,
                      bn: int = _pw8.DEFAULT_BN):
     """Compressed masked scan over int8 codes (integer MXU dot products +
     dequantized correction; :mod:`repro.kernels.pairwise_l2_int8`). The
-    bandwidth annotation counts the *compressed* byte stream."""
-    thunk = lambda: _pw8.pairwise_l2_int8(  # noqa: E731
-        queries, codes, scale, offset, sq_norm, lo, hi, ql, qh, mask,
-        bq=bq, bn=bn, interpret=_interpret())
-    if not obs.tracing():
-        return thunk()
+    span's ``bytes`` count the *compressed* byte stream."""
     Q, d = queries.shape
     N = codes.shape[0]
     nbytes = (pairwise_stream_bytes(Q, N, d, 1)
               + N * 4                   # sq_norm
               + 2 * d * 4)              # scale + offset
-    return _run_traced("pairwise_l2_int8", nbytes, thunk)
+    with _kernel_span("kernel:pairwise_l2_int8", nbytes):
+        return _pw8.pairwise_l2_int8(queries, codes, scale, offset, sq_norm,
+                                     lo, hi, ql, qh, mask, bq=bq, bn=bn,
+                                     interpret=_interpret())
 
 
 def gathered_l2(queries, cand_vecs, bq: int = _gl.DEFAULT_BQ):
-    thunk = lambda: _gl.gathered_l2(  # noqa: E731
-        queries, cand_vecs, bq=bq, interpret=_interpret())
-    if not obs.tracing():
-        return thunk()
-    return _run_traced("gathered_l2", _nbytes(queries, cand_vecs), thunk)
+    with _kernel_span("kernel:gathered_l2", _nbytes(queries, cand_vecs)):
+        return _gl.gathered_l2(queries, cand_vecs, bq=bq,
+                               interpret=_interpret())
 
 
 def gathered_l2_dot(queries, cand_vecs, bq: int = _gl.DEFAULT_BQ):
-    thunk = lambda: _gl.gathered_l2_dot(  # noqa: E731
-        queries, cand_vecs, bq=bq, interpret=_interpret())
-    if not obs.tracing():
-        return thunk()
-    return _run_traced("gathered_l2_dot", _nbytes(queries, cand_vecs), thunk)
+    with _kernel_span("kernel:gathered_l2_dot", _nbytes(queries, cand_vecs)):
+        return _gl.gathered_l2_dot(queries, cand_vecs, bq=bq,
+                                   interpret=_interpret())
 
 
 def gathered_topk(queries, vectors, ids, avail, b, e, version,
@@ -147,15 +122,13 @@ def gathered_topk(queries, vectors, ids, avail, b, e, version,
     """Fused wavefront step: gather-by-id + L2 + label mask + beam merge
     (:mod:`repro.kernels.gathered_topk`) in one kernel call."""
     from . import gathered_topk as _gt
-    thunk = lambda: _gt.gathered_topk(  # noqa: E731
-        queries, vectors, ids, avail, b, e, version, pool_ids, pool_d,
-        pool_exp, bq=bq or _gt.DEFAULT_BQ, interpret=_interpret())
-    if not obs.tracing():
-        return thunk()
     Q, d = queries.shape
     nbytes = gathered_stream_bytes(Q, ids.shape[1], pool_d.shape[1], d,
                                    vectors.dtype.itemsize)
-    return _run_traced("gathered_topk", nbytes, thunk)
+    with _kernel_span("kernel:gathered_topk", nbytes):
+        return _gt.gathered_topk(
+            queries, vectors, ids, avail, b, e, version, pool_ids, pool_d,
+            pool_exp, bq=bq or _gt.DEFAULT_BQ, interpret=_interpret())
 
 
 def gathered_topk_quant(queries, codes, scale, offset, ids, avail, b, e,
@@ -164,16 +137,15 @@ def gathered_topk_quant(queries, codes, scale, offset, ids, avail, b, e,
     int8/float16 rows and dequantizes in VMEM
     (:func:`repro.kernels.gathered_topk.gathered_topk_quant`)."""
     from . import gathered_topk as _gt
-    thunk = lambda: _gt.gathered_topk_quant(  # noqa: E731
-        queries, codes, scale, offset, ids, avail, b, e, version, pool_ids,
-        pool_d, pool_exp, bq=bq or _gt.DEFAULT_BQ, interpret=_interpret())
-    if not obs.tracing():
-        return thunk()
     Q, d = queries.shape
     nbytes = (gathered_stream_bytes(Q, ids.shape[1], pool_d.shape[1], d,
                                     codes.dtype.itemsize)
               + 2 * d * 4)              # scale + offset
-    return _run_traced("gathered_topk_quant", nbytes, thunk)
+    with _kernel_span("kernel:gathered_topk_quant", nbytes):
+        return _gt.gathered_topk_quant(
+            queries, codes, scale, offset, ids, avail, b, e, version,
+            pool_ids, pool_d, pool_exp, bq=bq or _gt.DEFAULT_BQ,
+            interpret=_interpret())
 
 
 # re-export oracles for convenience
@@ -187,13 +159,9 @@ gathered_topk_quant_ref = ref.gathered_topk_quant_ref
 def fused_topk_l2(queries, corpus, lo, hi, ql, qh, mask: int, k: int = 10,
                   bn: int = 1024):
     from . import fused_topk as _ft
-    thunk = lambda: _ft.fused_topk_l2(  # noqa: E731
-        queries, corpus, lo, hi, ql, qh, mask, k=k, bn=bn,
-        interpret=_interpret())
-    if not obs.tracing():
-        return thunk()
     Q, d = queries.shape
-    N = corpus.shape[0]
-    return _run_traced(
-        "fused_topk_l2",
-        pairwise_stream_bytes(Q, N, d, corpus.dtype.itemsize), thunk)
+    nbytes = pairwise_stream_bytes(Q, corpus.shape[0], d,
+                                   corpus.dtype.itemsize)
+    with _kernel_span("kernel:fused_topk_l2", nbytes):
+        return _ft.fused_topk_l2(queries, corpus, lo, hi, ql, qh, mask, k=k,
+                                 bn=bn, interpret=_interpret())

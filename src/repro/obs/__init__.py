@@ -11,9 +11,12 @@ distributed (and the benchmark drivers):
   :class:`StreamingHistogram` (formerly ``repro.serving.scheduler``) is the
   shared percentile structure.
 * **traces** (:mod:`repro.obs.trace`) — per-request span trees. Library
-  code calls :func:`span` unconditionally; with no tracer installed it
-  returns a no-op singleton (one thread-local read, zero allocation), so
-  instrumentation-off is the fast path. ``SearchRequest(trace=True)``
+  code calls :func:`span` unconditionally; with no tracer installed and no
+  ``jax.profiler`` session recording it returns a no-op singleton (one
+  thread-local read and one ``is_enabled()`` call, zero allocation), so
+  instrumentation-off is the fast path. While a profiler session records,
+  every span also writes a ``repro.<name>`` ``TraceAnnotation`` carrying
+  its ``set`` values, on the same clock as the device trace. ``SearchRequest(trace=True)``
   (or ``EngineConfig(trace_sample=...)``) rides a finished :class:`Trace`
   back on ``SearchResult.trace`` — export Chrome-trace JSON with
   ``.save()`` or print ``result.explain()``; ``with obs.capture() as tr:``
@@ -21,28 +24,25 @@ distributed (and the benchmark drivers):
 * **logs + profiling** (:mod:`repro.obs.log`, :mod:`repro.obs.profile`) —
   rate-limited structured progress logging (:func:`get_logger`), an opt-in
   ``jax.profiler`` capture wrapper (:func:`profiler_capture`), and the
-  per-``device_kind`` peak table (:data:`PEAKS`) + :func:`bandwidth_annotation`
-  used to annotate kernel spans with achieved-vs-peak bandwidth.
+  per-``device_kind`` peak table (:data:`PEAKS`).
 """
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, REGISTRY,
                       StreamingHistogram, get_registry, start_metrics_server)
-from .trace import (NULL_SPAN, Span, Trace, Tracer, active_tracer,
-                    begin_request_trace, capture, end_request_trace, span,
-                    tracing)
+from .trace import (NULL_SPAN, PROFILE_PREFIX, Span, Trace, Tracer,
+                    active_tracer, begin_request_trace, capture,
+                    end_request_trace, span, tracing)
 from .log import StructuredLogger, get_logger
-from .profile import (PEAKS, V5E, bandwidth_annotation, peaks,
-                      profiler_capture)
+from .profile import PEAKS, V5E, peaks, profiler_capture
 
 __all__ = [
     # metrics
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "StreamingHistogram", "get_registry", "start_metrics_server",
     # traces
-    "NULL_SPAN", "Span", "Trace", "Tracer", "active_tracer",
+    "NULL_SPAN", "PROFILE_PREFIX", "Span", "Trace", "Tracer", "active_tracer",
     "begin_request_trace", "capture", "end_request_trace", "span", "tracing",
     # logs
     "StructuredLogger", "get_logger",
     # profiling
-    "PEAKS", "V5E", "bandwidth_annotation", "peaks",
-    "profiler_capture",
+    "PEAKS", "V5E", "peaks", "profiler_capture",
 ]

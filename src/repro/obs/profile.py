@@ -1,4 +1,4 @@
-"""Profiling hooks: opt-in ``jax.profiler`` capture + roofline annotation.
+"""Profiling hooks: opt-in ``jax.profiler`` capture + published peaks.
 
 Two pieces:
 
@@ -7,23 +7,20 @@ Two pieces:
   the enclosed block. Opt-in and failure-tolerant: if the installed jax
   build lacks profiler support (or the capture races another one), the
   block still runs and the context records ``.error`` instead of raising —
-  profiling must never take down a serving process.
+  profiling must never take down a serving process. While it records,
+  every ``obs.span`` also lands in the profile as a ``repro.<name>``
+  annotation (:mod:`repro.obs.trace`).
 
-* :data:`PEAKS` + :func:`peaks` + :func:`bandwidth_annotation` — published
-  per-chip peaks keyed by JAX's ``device_kind`` (bf16 FLOP/s, HBM and ICI
-  link bandwidth), shared by ``repro.launch.roofline`` and the kernel spans.
-  A device missing from the table is an error, never a default.
-  ``bandwidth_annotation(nbytes, seconds, device_kind)`` turns a measured
-  kernel span into achieved GB/s and, for a device with a known peak, the
-  fraction of its HBM peak — attached to kernel spans by
-  ``repro.kernels.ops`` when tracing is on.
+* :data:`PEAKS` + :func:`peaks` — published per-chip peaks keyed by JAX's
+  ``device_kind`` (bf16 FLOP/s, HBM and ICI link bandwidth), read by
+  ``repro.launch.roofline`` and ``repro.launch.dryrun_mstg``. A device
+  missing from the table is an error, never a default.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional
 
-__all__ = ["PEAKS", "V5E", "bandwidth_annotation", "peaks",
-           "profiler_capture"]
+__all__ = ["PEAKS", "V5E", "peaks", "profiler_capture"]
 
 V5E = "TPU v5 lite"     # jax's device_kind of a TPU v5e chip
 
@@ -43,26 +40,6 @@ def peaks(device_kind: str) -> Dict[str, float]:
         raise KeyError(f"no published peaks for device_kind "
                        f"{device_kind!r}; add a sourced row to "
                        f"repro.obs.profile.PEAKS") from None
-
-
-def bandwidth_annotation(nbytes: float, seconds: float,
-                         device_kind: Optional[str] = None
-                         ) -> Dict[str, float]:
-    """Achieved memory bandwidth of a measured region.
-
-    Returns ``{"bytes", "gb_per_s"}`` plus ``"frac_of_peak"`` (of the HBM
-    peak of ``device_kind``, which must be in :data:`PEAKS`) when a device
-    kind is given — the dict a kernel span attaches via ``sp.set``. Off the
-    accelerator pass ``None``: a host-clock rate of interpret-mode code has
-    no device peak to be a share of. ``seconds <= 0`` reports 0 bandwidth
-    rather than dividing by zero (a clock can quantize to 0 on tiny
-    kernels)."""
-    gbs = (nbytes / seconds / 1e9) if seconds > 0 else 0.0
-    out = {"bytes": float(nbytes), "gb_per_s": round(gbs, 3)}
-    if device_kind is not None:
-        out["frac_of_peak"] = round(gbs * 1e9 / peaks(device_kind)["hbm_bw"],
-                                    6)
-    return out
 
 
 class profiler_capture:

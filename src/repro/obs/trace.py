@@ -6,9 +6,10 @@ tracers freeze into a :class:`Trace` that exports Chrome-trace/Perfetto JSON
 (:meth:`Trace.render`, which backs ``SearchResult.explain()``).
 
 The instrumentation contract is a **no-op fast path**: library code calls the
-module-level :func:`span` unconditionally; when no tracer is installed it
-returns the singleton :data:`NULL_SPAN` — one thread-local attribute read,
-no allocation, no dict churn — so always-on instrumentation costs nothing on
+module-level :func:`span` unconditionally; when no tracer is installed and
+no profiler session records it returns the singleton :data:`NULL_SPAN` —
+one thread-local attribute read and one ``is_enabled()`` call, no
+allocation, no dict churn — so always-on instrumentation costs nothing on
 untraced requests. Annotations attach via ``sp.set("key", value)``
 (positional, so the disabled path never builds a kwargs dict) and should sit
 behind ``if obs.tracing():`` when computing the value itself is not free.
@@ -26,19 +27,39 @@ ShardedDeployment`, :class:`repro.streaming.SegmentedIndex`) installs a
 Spans support both ``with`` blocks and explicit start/stop (``sp =
 obs.span("jit_region"); ...; sp.stop()``) for regions whose boundaries do
 not nest lexically (dispatch vs device completion of a jit call).
+
+**Profiler bridge.** While a ``jax.profiler`` session records (``with
+jax.profiler.trace(dir):``, :func:`repro.obs.profiler_capture`), every span
+also writes a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``,
+with or without a tracer installed, and each ``sp.set(key, value)`` lands
+on it as an event stat (ints and floats as numbers, strings as they are,
+anything else as its ``repr``). The host events then sit on the
+profiler's clock beside the device's, so a device idle gap can be put down
+to the program span that covers it. The session is the switch: with no
+tracer and no session, :func:`span` costs one thread-local read plus one
+``TraceAnnotation.is_enabled()`` call and returns :data:`NULL_SPAN`.
+:func:`tracing` is true while either sink is live.
 """
 from __future__ import annotations
 
 import json
+import numbers
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "Trace", "NULL_SPAN", "span", "tracing",
-           "active_tracer", "capture", "begin_request_trace",
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "Tracer", "Trace", "NULL_SPAN", "PROFILE_PREFIX", "span",
+           "tracing", "active_tracer", "capture", "begin_request_trace",
            "end_request_trace"]
 
 _STATE = threading.local()
+
+# name prefix of the annotations spans write into a jax.profiler session
+PROFILE_PREFIX = "repro."
+# true only while a profiler session records (a cheap native call)
+_profiling = TraceAnnotation.is_enabled
 
 
 def active_tracer() -> Optional["Tracer"]:
@@ -47,8 +68,9 @@ def active_tracer() -> Optional["Tracer"]:
 
 
 def tracing() -> bool:
-    """True when a tracer is installed — guard for non-free annotations."""
-    return getattr(_STATE, "tracer", None) is not None
+    """True when a tracer is installed or a profiler session records —
+    guard for non-free annotations."""
+    return getattr(_STATE, "tracer", None) is not None or _profiling()
 
 
 class _NullSpan:
@@ -109,6 +131,49 @@ class Span:
     def duration_ms(self) -> float:
         end = self.t_stop if self.t_stop is not None else self._tracer.clock()
         return (end - self.t_start) * 1e3
+
+
+def _stat(value: Any):
+    """A span annotation as a profiler event stat: numbers stay numbers."""
+    if isinstance(value, numbers.Integral):
+        return int(value)
+    if isinstance(value, numbers.Real):
+        return float(value)
+    return value if isinstance(value, str) else repr(value)
+
+
+class _ProfiledSpan:
+    """A span while a profiler session records: a ``TraceAnnotation``
+    named ``repro.<name>``, opened here and closed by ``stop()`` on the same
+    thread, plus the tracer's :class:`Span` when one is installed
+    (:data:`NULL_SPAN` otherwise). ``set`` writes to both."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, name: str, inner: Any):
+        self._span = inner
+        self._ann = TraceAnnotation(PROFILE_PREFIX + name)
+        self._ann.__enter__()
+
+    def set(self, key: str, value: Any) -> "_ProfiledSpan":
+        self._span.set(key, value)
+        if self._ann is not None:
+            self._ann.set_metadata(**{key: _stat(value)})
+        return self
+
+    def stop(self) -> "_ProfiledSpan":
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self._span.stop()
+        return self
+
+    def __enter__(self) -> "_ProfiledSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.stop()
+        return False
 
 
 class Tracer:
@@ -226,12 +291,14 @@ def _compact(v) -> str:
 # ---- module-level instrumentation surface ----------------------------------
 
 def span(name: str) -> Any:
-    """Open a span on the active tracer; :data:`NULL_SPAN` when tracing is
-    off (the no-op fast path: one thread-local read, zero allocation)."""
+    """Open a span on the active tracer and, while a profiler session
+    records, as a ``repro.<name>`` annotation in the profile;
+    :data:`NULL_SPAN` when neither is live (the no-op fast path: one
+    thread-local read and one ``is_enabled()`` call, zero allocation)."""
     t = getattr(_STATE, "tracer", None)
-    if t is None:
-        return NULL_SPAN
-    return t.span(name)
+    if not _profiling():
+        return NULL_SPAN if t is None else t.span(name)
+    return _ProfiledSpan(name, NULL_SPAN if t is None else t.span(name))
 
 
 class capture:

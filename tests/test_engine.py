@@ -9,7 +9,9 @@ from hypothesis import given, settings, strategies as hst
 from repro.core import (ANY_OVERLAP, QUERY_CONTAINED, QUERY_CONTAINING,
                         EngineConfig, MSTGIndex, Overlaps, QueryEngine,
                         SearchRequest, intervals as iv)
-from repro.core.engine import ROUTE_GRAPH, ROUTE_PRUNED, _next_pow2
+from repro.core import segment_tree as st
+from repro.core.engine import (ROUTE_GRAPH, ROUTE_PRUNED, _next_pow2,
+                               _scan_rows)
 from repro.data import make_queries, brute_force_topk
 
 
@@ -144,6 +146,52 @@ def test_engine_pruned_exact_despite_bad_estimator(small_ds, built_index):
     pids, pds = eng.search_pruned(ds.queries, qlo, qhi, ANY_OVERLAP, k=10)
     np.testing.assert_allclose(np.sort(pds, 1), np.sort(tds, 1),
                                rtol=1e-4, atol=1e-4)
+
+
+def _needed_rows(index, slots) -> int:
+    """The candidate rows a plan's slot scans need, recounted on the host:
+    for each query of each slot, the members of every node of its key
+    range's decomposition inserted at or before its version."""
+    total = 0
+    for s in slots:
+        fv = index.variants[s.variant]
+        for ver, lo, hi in zip(s.version, s.key_lo, s.key_hi):
+            for lvl, idx in st.decompose(int(lo), int(hi), fv.Kpad):
+                a, b = fv.node_off[lvl, idx], fv.node_off[lvl, idx + 1]
+                total += int(np.sum(fv.member_ver[lvl, a:b] <= ver))
+    return total
+
+
+@pytest.mark.parametrize("Q", [12, 8], ids=["padded", "unpadded"])
+@pytest.mark.parametrize("mask", [1, 2, 3, 4, 8, 10, 12, ANY_OVERLAP])
+def test_pruned_scan_row_counters(small_ds, built_index, mask, Q):
+    """Each slot scan needs no more rows than a scan to the batch's longest
+    prefix runs, which runs no more than the scan loop does (the bound
+    that keeps the pruned route exact); the needed rows match a host
+    recount; the registry rises by the same sums."""
+    from repro import obs
+    ds = small_ds
+    eng = QueryEngine(built_index)
+    qlo, qhi = make_queries(ds, mask, 0.15, seed=31)
+    queries, qlo, qhi = ds.queries[:Q], qlo[:Q], qhi[:Q]
+    slots = eng.plan(mask, qlo, qhi)
+    *_, scans = eng._run_pruned(queries, qlo, qhi, mask, 10, slots=slots)
+    rows = _scan_rows(scans)
+    Qp = _next_pow2(Q)
+    for (needed, to_longest, scanned), (total, max_blocks, block) in zip(
+            rows, scans):
+        assert total.shape == (Qp,)
+        assert 0 <= needed <= to_longest <= scanned
+        assert scanned == Qp * max_blocks * block
+    assert rows[:, 0].sum() == _needed_rows(built_index, slots)
+
+    counter = obs.get_registry().counter("engine_pruned_rows_total",
+                                         labels=("kind",))
+    kinds = ("needed", "to_longest", "scanned")
+    before = [counter.value(kind=k) for k in kinds]
+    eng.execute(_req(queries, qlo, qhi, mask, route=ROUTE_PRUNED, k=10))
+    rose = [counter.value(kind=k) - b for k, b in zip(kinds, before)]
+    assert rose == list(rows.sum(axis=0))
 
 
 def test_engine_empty_batch_and_empty_predicate(built_index, small_ds):

@@ -184,7 +184,8 @@ def test_noop_fast_path():
     with obs.span("ctx") as c:
         assert c is obs.NULL_SPAN
     # overhead smoke: the disabled path must stay sub-10us per span (it is
-    # one thread-local read; the bound is lenient for noisy CI boxes)
+    # one thread-local read and one is_enabled() call; the bound is lenient
+    # for noisy CI boxes)
     import time
     n = 20_000
     t0 = time.perf_counter()
@@ -347,34 +348,150 @@ def test_progress_rate_limit():
     assert lg.progress("other", every_s=60.0) is True           # per-event
 
 
-def test_bandwidth_annotation():
-    from repro.obs.profile import V5E, bandwidth_annotation, peaks
-    hbm = peaks(V5E)["hbm_bw"]
-    ann = bandwidth_annotation(hbm, 1.0, V5E)    # one peak-second of bytes
-    assert ann["frac_of_peak"] == pytest.approx(1.0)
-    assert ann["gb_per_s"] == pytest.approx(hbm / 1e9)
-    assert bandwidth_annotation(1024, 0.0)["gb_per_s"] == 0.0
-    # off the accelerator there is no peak to take a share of
-    assert "frac_of_peak" not in bandwidth_annotation(hbm, 1.0)
+def test_peaks_of_an_unknown_chip_raise():
+    from repro.obs.profile import V5E, peaks
+    assert peaks(V5E)["hbm_bw"] == 819e9
     # an unknown chip is an error, never a v5e default
     with pytest.raises(KeyError, match="no published peaks"):
-        bandwidth_annotation(hbm, 1.0, "TPU v99")
+        peaks("TPU v99")
 
 
-def test_kernel_span_records_bandwidth(small_ds):
+def test_kernel_span_records_bandwidth(small_ds, monkeypatch):
+    """A kernel span carries the kernel's modelled bytes, does not wait for
+    the kernel, and leaves the answer as the untraced call gives it."""
+    import jax
     import jax.numpy as jnp
     from repro.kernels import ops
     ds = small_ds
     q = jnp.asarray(ds.queries[:2])
-    cand = jnp.asarray(np.broadcast_to(ds.vectors[:8],
-                                       (2, 8, ds.vectors.shape[1])).copy())
-    ref = np.asarray(ops.gathered_l2(q, cand))   # untraced
-    t = obs.begin_request_trace()
-    traced = np.asarray(ops.gathered_l2(q, cand))
-    trace = obs.end_request_trace(t)
-    np.testing.assert_allclose(traced, ref)
-    sp = trace.roots[0]
-    assert sp.name == "kernel:gathered_l2"
-    assert {"bytes", "gb_per_s"} <= set(sp.args)
-    # interpret mode on the CPU: a host-clock rate, no device peak share
-    assert "frac_of_peak" not in sp.args
+    corpus = jnp.asarray(ds.vectors[:128])
+    cand = jnp.asarray(np.broadcast_to(ds.vectors[:8], (2, 8, ds.d)).copy())
+    calls = {
+        "pairwise_l2_masked": (
+            lambda: ops.pairwise_l2_masked(
+                q, corpus, jnp.asarray(ds.lo[:128], jnp.float32),
+                jnp.asarray(ds.hi[:128], jnp.float32),
+                jnp.full(2, -1e9, jnp.float32), jnp.full(2, 1e9, jnp.float32),
+                ANY_OVERLAP, bq=8, bn=128),
+            ops.pairwise_stream_bytes(2, 128, ds.d, 4)),
+        "gathered_l2": (lambda: ops.gathered_l2(q, cand),
+                        q.nbytes + cand.nbytes),
+    }
+    untraced = {name: np.asarray(call()) for name, (call, _) in calls.items()}
+
+    def no_wait(*a, **k):
+        raise AssertionError("a traced kernel call waited on the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", no_wait)
+    for name, (call, nbytes) in calls.items():
+        t = obs.begin_request_trace()
+        traced = np.asarray(call())
+        trace = obs.end_request_trace(t)
+        np.testing.assert_array_equal(traced, untraced[name])
+        (sp,) = trace.roots
+        assert sp.name == f"kernel:{name}"
+        assert sp.args == {"bytes": nbytes}
+
+
+# ---- profiler bridge -------------------------------------------------------
+
+def _profile_events(log_dir):
+    """``(name, start_ns, end_ns, stats)`` of the program's annotations
+    (``repro.`` names) in the profile written under ``log_dir``."""
+    import glob
+    import os
+    import warnings
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = []
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(obs.PROFILE_PREFIX):
+                        out.append((ev.name, ev.start_ns, ev.end_ns,
+                                    dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_span_writes_an_annotation_while_a_profiler_records(tmp_path):
+    import jax
+    assert not obs.tracing() and obs.span("off") is obs.NULL_SPAN
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs.tracing() and obs.active_tracer() is None
+        with obs.span("outer") as o:
+            assert o is not obs.NULL_SPAN
+            o.set("n", np.int64(3)).set("f", 0.5).set("variant", "T")
+            o.set("shape", (2, 3))
+            inner = obs.span("inner")        # explicit start/stop region
+            inner.set("k", 7)
+            inner.stop()
+            inner.stop()                     # a second stop is a no-op
+    # the session is over: the fast path is back
+    assert not obs.tracing() and obs.span("off") is obs.NULL_SPAN
+    ev = {name: (s, e, st) for name, s, e, st in _profile_events(tmp_path)}
+    assert set(ev) == {"repro.outer", "repro.inner"}
+    assert ev["repro.outer"][2] == {"n": 3, "f": 0.5, "variant": "T",
+                                    "shape": "(2, 3)"}
+    assert ev["repro.inner"][2] == {"k": 7}
+    (os_, oe, _), (is_, ie, _) = ev["repro.outer"], ev["repro.inner"]
+    assert os_ <= is_ <= ie <= oe
+
+
+def test_pruned_execute_under_profiler_writes_spans_and_rows(
+        small_ds, built_index, tmp_path):
+    """One pruned-route execute under a profiler session: every span of the
+    route lands in the profile, and ``repro.pruned`` carries the scan's row
+    counts, equal to what the registry counted for the call."""
+    import jax
+    ds = small_ds
+    eng = QueryEngine(built_index)
+    qlo, qhi = make_queries(ds, ANY_OVERLAP, 0.15, seed=31)
+    req = _req(ds, qlo, qhi, route="pruned")
+    eng.execute(req)                         # compile outside the session
+    rows = obs.get_registry().counter("engine_pruned_rows_total",
+                                      labels=("kind",))
+    kinds = ("needed", "scanned", "to_longest")
+    before = {k: rows.value(kind=k) for k in kinds}
+    with jax.profiler.trace(str(tmp_path)):
+        res = eng.execute(req)
+    events = _profile_events(tmp_path)
+    names = {name for name, *_ in events}
+    for want in ("search", "plan", "pruned", "slot", "merge", "fetch"):
+        assert "repro." + want in names, names
+    (pruned,) = [st for name, _, _, st in events if name == "repro.pruned"]
+    for k in kinds:
+        assert pruned[f"rows_{k}"] == rows.value(kind=k) - before[k]
+    assert 0 < pruned["rows_needed"] <= pruned["rows_to_longest"] \
+        <= pruned["rows_scanned"]
+    assert pruned["slots"] == res.report.slot_count == 2
+    slot_stats = [st for name, _, _, st in events if name == "repro.slot"]
+    assert len(slot_stats) == 2
+    assert all(st["rows"] == 16 and st["block"] == 256 for st in slot_stats)
+    assert pruned["rows_scanned"] == sum(16 * st["max_blocks"] * 256
+                                         for st in slot_stats)
+
+
+def test_tracer_and_profiler_record_the_same_spans(small_ds, built_index,
+                                                   tmp_path):
+    import jax
+    ds = small_ds
+    eng = QueryEngine(built_index)
+    qlo, qhi = make_queries(ds, ANY_OVERLAP, 0.15, seed=31)
+    req = _req(ds, qlo, qhi, route="pruned", trace=True)
+    eng.execute(req)
+    with jax.profiler.trace(str(tmp_path)):
+        res = eng.execute(req)
+    tree = res.trace.span_names()
+    prof = [name[len(obs.PROFILE_PREFIX):]
+            for name, *_ in _profile_events(tmp_path)]
+    assert sorted(prof) == sorted(tree)
+    assert {"search", "plan", "pruned", "slot", "merge",
+            "fetch"} <= set(tree)
+    pruned = next(sp for sp, _ in res.trace.walk() if sp.name == "pruned")
+    assert {"slots", "rows_needed", "rows_scanned",
+            "rows_to_longest"} <= set(pruned.args)
