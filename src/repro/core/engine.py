@@ -86,7 +86,7 @@ ROUTE_PRUNED = "pruned"
 ROUTE_FLAT = "flat"
 _ROUTES = (ROUTE_AUTO, ROUTE_GRAPH, ROUTE_PRUNED, ROUTE_FLAT)
 # kinds of engine_pruned_rows_total, in the column order of _scan_rows
-_ROW_KINDS = ("needed", "to_longest", "scanned")
+_ROW_KINDS = ("needed", "to_longest", "scanned", "bound")
 
 
 def _next_pow2(x: int) -> int:
@@ -128,19 +128,23 @@ def _empty_result(Q: int, k: int) -> Tuple[np.ndarray, np.ndarray]:
 
 
 def _scan_rows(scans: List[tuple]) -> np.ndarray:
-    """Candidate rows of each pruned slot scan, (slots, 3) int64 in
+    """Candidate rows of each pruned slot scan, (slots, 4) int64 in
     :data:`_ROW_KINDS` order: *needed*, the sum of the queries' candidate
-    prefixes; *to_longest*, what a loop stopped at the batch's longest
-    prefix would run (Qp x that prefix in whole blocks); *scanned*, what the
-    loop runs (Qp x max_blocks x block). ``scans`` holds ``(total,
-    max_blocks, block)`` per slot, ``total`` the scan's (Qp,) device array of
-    prefix lengths; every slot's totals come back in one fetch."""
-    totals = jax.device_get([t for t, _, _ in scans])
+    prefixes; *to_longest*, the batch's longest prefix in whole blocks, for
+    every query (Qp x that x block), recounted here from the prefixes;
+    *scanned*, what the loop ran (Qp x n_run x block); *bound*, its static
+    cap (Qp x max_blocks x block), what a loop of fixed length would run.
+    ``scans`` holds ``(total, n_run, max_blocks, block)`` per slot,
+    ``total`` the scan's (Qp,) device array of prefix lengths and ``n_run``
+    its device scalar of blocks run; every slot's come back in one fetch."""
+    fetched = jax.device_get([(t, r) for t, r, _, _ in scans])
     out = np.zeros((len(scans), len(_ROW_KINDS)), np.int64)
-    for i, (tot, (_, max_blocks, block)) in enumerate(zip(totals, scans)):
+    for i, ((tot, n_run), (*_, max_blocks, block)) in enumerate(
+            zip(fetched, scans)):
         tot = np.asarray(tot, np.int64)
         longest = -(-int(tot.max(initial=0)) // block)
         out[i] = (tot.sum(), tot.size * longest * block,
+                  tot.size * int(n_run) * block,
                   tot.size * max_blocks * block)
     return out
 
@@ -411,7 +415,8 @@ class QueryEngine:
                              "Candidate rows of the pruned route's slot "
                              "scans: needed by the queries, to_longest (a "
                              "scan stopped at the batch's longest prefix), "
-                             "scanned (what the scan loop runs)",
+                             "scanned (what the scan loop ran), bound (the "
+                             "loop's cap, max_blocks)",
                              labels=("kind",))
         self._m_rows = tuple(rows_c.labels(kind=kind) for kind in _ROW_KINDS)
 
@@ -765,9 +770,10 @@ class QueryEngine:
         for child, v in zip(self._m_rows, rows):
             child.inc(float(v))
         if obs.tracing():
-            needed, to_longest, scanned = (int(v) for v in rows)
+            needed, to_longest, scanned, bound = (int(v) for v in rows)
             sp.set("slots", len(scans)).set("rows_needed", needed)
             sp.set("rows_scanned", scanned).set("rows_to_longest", to_longest)
+            sp.set("rows_bound", bound)
 
     def _rerank_width(self, k: int, upper: Optional[int] = None) -> int:
         """Approximate candidates per query surviving to the exact re-rank:
@@ -865,7 +871,9 @@ class QueryEngine:
             # exact candidate upper bound for this slot: objects with
             # sort_rank <= max version (key-range pruning only shrinks it),
             # rounded to a power of two so max_blocks hits the jit cache —
-            # never truncates, so the pruned route stays recall-1.0
+            # never truncates, so the pruned route stays recall-1.0. It only
+            # caps the scan loop, which stops on the device at the batch's
+            # longest candidate prefix, so the host never waits to size it
             if max_candidates is not None:
                 cap = min(n, int(max_candidates))
             else:
@@ -882,7 +890,7 @@ class QueryEngine:
                 ssp.set("variant", s.variant).set("candidates", cap)
                 ssp.set("rows", Qp).set("max_blocks", max_blocks)
                 ssp.set("block", block)
-                ids, d, total = _pruned_search_variant(
+                ids, d, total, n_run = _pruned_search_variant(
                     self.pruned_dev(s.variant), self.lo, self.hi, qdev,
                     qlo_j, qhi_j, jnp.asarray(s.version, jnp.int32),
                     jnp.asarray(s.key_lo, jnp.int32), jnp.asarray(s.key_hi, jnp.int32),
@@ -891,7 +899,8 @@ class QueryEngine:
             # start the totals' copy to the host now, so that it is there by
             # the time the answers are: fetching them then costs no wait
             total.copy_to_host_async()
-            scans.append((total, max_blocks, block))
+            n_run.copy_to_host_async()
+            scans.append((total, n_run, max_blocks, block))
             if res is None:
                 res = (ids, d)
             else:

@@ -112,10 +112,14 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
     predicate on gathered candidates (cheap; guards rank-boundary ties and
     lets one variant serve any sub-mask of its plan).
 
-    Returns ``(ids, dists, total)``: ``total`` is each query's (Q,) int32
-    candidate-prefix length, the rows its answer needs. The loop runs
-    ``max_blocks * block`` rows for every query; the answer is exact only
-    while ``total <= max_blocks * block``."""
+    Returns ``(ids, dists, total, n_run)``: ``total`` is each query's (Q,)
+    int32 candidate-prefix length, the rows its answer needs; ``n_run`` is
+    the int32 count of blocks the loop ran. The loop stops on the device at
+    the batch's longest prefix, ``n_run = min(max_blocks, ceil(max(total) /
+    block))``: past it every query's rows are out of its prefix, so a block
+    there could only add ``INF`` / ``NO_EDGE`` entries. ``max_blocks`` is
+    the static cap; the answer is exact only while ``total <= max_blocks *
+    block``."""
     # quantized layouts carry "codes" (+ affine params) instead of a float32
     # "vectors" table; dict keys are static under jit, so this picks the
     # gather source at trace time with no runtime branch
@@ -166,8 +170,10 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
     top_d = jnp.full((Q, k), INF, jnp.float32)
     top_i = jnp.full((Q, k), NO_EDGE, jnp.int32)
 
-    def body(carry, blk):
-        top_d, top_i = carry
+    n_run = jnp.minimum(max_blocks, (jnp.max(total) + block - 1) // block)
+
+    def body(carry):
+        blk, top_d, top_i = carry
         pos = blk * block + jnp.arange(block)                     # (B,) candidate positions
         # map candidate position -> (node slot, offset within prefix)
         slot = jnp.sum(pos[None, :, None] >= cum[:, None, :], axis=2)   # (Q, B)
@@ -196,10 +202,12 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
         cat_d = jnp.concatenate([top_d, dist], axis=1)
         cat_i = jnp.concatenate([top_i, jnp.where(sel, cand, NO_EDGE)], axis=1)
         neg, pos_k = jax.lax.top_k(-cat_d, k)
-        return (( -neg, jnp.take_along_axis(cat_i, pos_k, 1))), None
+        return blk + 1, -neg, jnp.take_along_axis(cat_i, pos_k, 1)
 
-    (top_d, top_i), _ = jax.lax.scan(body, (top_d, top_i), jnp.arange(max_blocks))
-    return top_i, top_d, total
+    _, top_d, top_i = jax.lax.while_loop(
+        lambda carry: carry[0] < n_run, body,
+        (jnp.zeros((), jnp.int32), top_d, top_i))
+    return top_i, top_d, total, n_run
 
 
 # The host-facing exact-search API is QueryEngine (repro.core.engine) with

@@ -2,6 +2,7 @@
 and the QueryEngine facade (routing, padding, end-to-end recall)."""
 import dataclasses
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as hst
@@ -12,6 +13,8 @@ from repro.core import (ANY_OVERLAP, QUERY_CONTAINED, QUERY_CONTAINING,
 from repro.core import segment_tree as st
 from repro.core.engine import (ROUTE_GRAPH, ROUTE_PRUNED, _next_pow2,
                                _scan_rows)
+from repro.core.flat import _pruned_search_variant
+from repro.core.hnsw import NO_EDGE
 from repro.data import make_queries, brute_force_topk
 
 
@@ -165,8 +168,8 @@ def _needed_rows(index, slots) -> int:
 @pytest.mark.parametrize("Q", [12, 8], ids=["padded", "unpadded"])
 @pytest.mark.parametrize("mask", [1, 2, 3, 4, 8, 10, 12, ANY_OVERLAP])
 def test_pruned_scan_row_counters(small_ds, built_index, mask, Q):
-    """Each slot scan needs no more rows than a scan to the batch's longest
-    prefix runs, which runs no more than the scan loop does (the bound
+    """Each slot scan needs no more rows than the batch's longest prefix
+    holds, which is what the scan loop ran, no more than its cap (the bound
     that keeps the pruned route exact); the needed rows match a host
     recount; the registry rises by the same sums."""
     from repro import obs
@@ -178,20 +181,137 @@ def test_pruned_scan_row_counters(small_ds, built_index, mask, Q):
     *_, scans = eng._run_pruned(queries, qlo, qhi, mask, 10, slots=slots)
     rows = _scan_rows(scans)
     Qp = _next_pow2(Q)
-    for (needed, to_longest, scanned), (total, max_blocks, block) in zip(
-            rows, scans):
+    for (needed, to_longest, scanned, bound), (total, _, max_blocks,
+                                               block) in zip(rows, scans):
         assert total.shape == (Qp,)
-        assert 0 <= needed <= to_longest <= scanned
-        assert scanned == Qp * max_blocks * block
+        assert 0 <= needed <= to_longest == scanned <= bound
+        assert bound == Qp * max_blocks * block
     assert rows[:, 0].sum() == _needed_rows(built_index, slots)
 
     counter = obs.get_registry().counter("engine_pruned_rows_total",
                                          labels=("kind",))
-    kinds = ("needed", "to_longest", "scanned")
+    kinds = ("needed", "to_longest", "scanned", "bound")
     before = [counter.value(kind=k) for k in kinds]
     eng.execute(_req(queries, qlo, qhi, mask, route=ROUTE_PRUNED, k=10))
     rose = [counter.value(kind=k) - b for k, b in zip(kinds, before)]
     assert rose == list(rows.sum(axis=0))
+
+
+def _assert_exact(ds, queries, qlo, qhi, mask, ids, d, k=10):
+    """Served answers against brute force: the same distances rank by rank,
+    and every id named qualifies and sits at the distance served."""
+    _, tds = brute_force_topk(ds.vectors, ds.lo, ds.hi, queries, qlo, qhi,
+                              mask, k)
+    np.testing.assert_allclose(d, tds, rtol=1e-5, atol=1e-5)
+    for qi in range(queries.shape[0]):
+        got = ids[qi][ids[qi] >= 0]
+        assert got.size == np.isfinite(tds[qi]).sum()
+        assert np.asarray(iv.eval_predicate(mask, ds.lo[got], ds.hi[got],
+                                            qlo[qi], qhi[qi])).all()
+        diff = ds.vectors[got] - queries[qi]
+        np.testing.assert_allclose(np.einsum("nd,nd->n", diff, diff),
+                                   d[qi, :got.size], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mask,Q,tier", [
+    *[(m, Q, "float32") for m in (1, 4, 12, ANY_OVERLAP) for Q in (12, 8)],
+    (ANY_OVERLAP, 12, "int8")])
+def test_pruned_scan_stops_at_longest_prefix(small_ds, built_index, mask, Q,
+                                             tier):
+    """The slot scan's loop stops at the batch's longest candidate prefix
+    whatever its cap and block: answers at the default cap, at the widest
+    cap and at many small blocks agree bit for bit, and with brute force."""
+    ds = small_ds
+    eng = QueryEngine(built_index, config=EngineConfig(storage_dtype=tier))
+    qlo, qhi = make_queries(ds, mask, 0.15, seed=31)
+    queries, qlo, qhi = ds.queries[:Q], qlo[:Q], qhi[:Q]
+    runs = ({}, {"max_candidates": ds.vectors.shape[0]}, {"block": 8})
+    (ids, d), *others = [eng.search_pruned(queries, qlo, qhi, mask, k=10,
+                                           **kw) for kw in runs]
+    for ids_o, d_o in others:
+        np.testing.assert_array_equal(ids_o, ids)
+        np.testing.assert_array_equal(d_o, d)
+    _assert_exact(ds, queries, qlo, qhi, mask, ids, d)
+    for kw in runs:
+        *_, scans = eng._run_pruned(queries, qlo, qhi, mask, 10, **kw)
+        for total, n_run, max_blocks, block in scans:
+            longest = int(np.asarray(total).max())
+            assert int(n_run) == -(-longest // block) <= max_blocks
+
+
+def test_pruned_scan_of_one_row_runs_one_block(built_index):
+    """A slot whose every task is empty but one, whose prefix is one row:
+    the loop runs one block, and the answer is that row."""
+    eng = QueryEngine(built_index)
+    fv = built_index.variants["T"]
+
+    def first_member(key):
+        """The node of a one-key range, and its first member's slice index
+        if that member alone is in the prefix at its own version."""
+        (lvl, node), = st.decompose(key, key, fv.Kpad)
+        a, b = fv.node_off[lvl, node], fv.node_off[lvl, node + 1]
+        alone = b == a + 1 or (b > a + 1 and fv.member_ver[lvl, a]
+                               < fv.member_ver[lvl, a + 1])
+        return (lvl, a) if alone else None
+
+    key, (lvl, a) = next((j, m) for j in range(fv.Kpad)
+                         if (m := first_member(j)) is not None)
+    row = int(fv.members[lvl, a])
+    Q = 8
+    version = np.full(Q, -1, np.int64)
+    key_lo, key_hi = np.ones(Q, np.int64), np.zeros(Q, np.int64)
+    version[0] = fv.member_ver[lvl, a]
+    key_lo[0] = key_hi[0] = key
+    slot = iv.PlanSlot("T", version, key_lo, key_hi)
+    qlo = np.full(Q, built_index.lo[row])
+    qhi = np.full(Q, built_index.hi[row])
+    queries = np.zeros((Q, built_index.vectors.shape[1]), np.float32)
+    ids, d, scans = eng._run_pruned(queries, qlo, qhi, ANY_OVERLAP, 10,
+                                    slots=[slot])
+    (total, n_run, _, _), = scans
+    np.testing.assert_array_equal(np.asarray(total), [1] + [0] * (Q - 1))
+    assert int(n_run) == 1
+    ids = np.asarray(ids)
+    assert ids[0, 0] == row and (ids[0, 1:] < 0).all() and (ids[1:] < 0).all()
+    assert np.isfinite(np.asarray(d)[0, 0])
+
+
+def test_pruned_scan_longest_prefix_in_whole_blocks(small_ds, built_index):
+    """A batch whose longest prefix is an exact multiple of the block: the
+    loop runs exactly that many blocks, drops none, and answers as brute
+    force does."""
+    ds = small_ds
+    eng = QueryEngine(built_index)
+    mask = 1                                    # a single-slot plan
+    qlo, qhi = make_queries(ds, mask, 0.15, seed=31)
+    queries, qlo, qhi = ds.queries[:8], qlo[:8], qhi[:8]
+    *_, scans = eng._run_pruned(queries, qlo, qhi, mask, 10)
+    (total, *_), = scans
+    longest = int(np.asarray(total).max())
+    block = next(b for b in range(longest // 2, 0, -1) if longest % b == 0)
+    ids, d, scans = eng._run_pruned(queries, qlo, qhi, mask, 10, block=block)
+    (_, n_run, _, _), = scans
+    assert longest // block >= 2 and int(n_run) == longest // block
+    rows = _scan_rows(scans)[0]
+    assert rows[1] == rows[2] == 8 * longest
+    _assert_exact(ds, queries, qlo, qhi, mask, np.asarray(ids), np.asarray(d))
+
+
+def test_pruned_scan_of_empty_tasks_runs_no_block(built_index):
+    """All prefixes empty: the loop runs no block and every answer is
+    ``NO_EDGE`` at ``inf``."""
+    eng = QueryEngine(built_index)
+    Q, k = 8, 10
+    queries = jnp.zeros((Q, built_index.vectors.shape[1]), jnp.float32)
+    ql = jnp.zeros(Q, jnp.float32)
+    ids, d, total, n_run = _pruned_search_variant(
+        eng.pruned_dev("T"), eng.lo, eng.hi, queries, ql, ql + 1e9,
+        jnp.full(Q, -1, jnp.int32), jnp.ones(Q, jnp.int32),
+        jnp.zeros(Q, jnp.int32), pred_mask_bits=ANY_OVERLAP, k=k,
+        Kpad=built_index.variants["T"].Kpad, block=256, max_blocks=3)
+    assert int(n_run) == 0 and not np.asarray(total).any()
+    np.testing.assert_array_equal(np.asarray(ids), np.full((Q, k), NO_EDGE))
+    assert np.isinf(np.asarray(d)).all()
 
 
 def test_engine_empty_batch_and_empty_predicate(built_index, small_ds):

@@ -455,7 +455,7 @@ def test_pruned_execute_under_profiler_writes_spans_and_rows(
     eng.execute(req)                         # compile outside the session
     rows = obs.get_registry().counter("engine_pruned_rows_total",
                                       labels=("kind",))
-    kinds = ("needed", "scanned", "to_longest")
+    kinds = ("needed", "scanned", "to_longest", "bound")
     before = {k: rows.value(kind=k) for k in kinds}
     with jax.profiler.trace(str(tmp_path)):
         res = eng.execute(req)
@@ -467,13 +467,13 @@ def test_pruned_execute_under_profiler_writes_spans_and_rows(
     for k in kinds:
         assert pruned[f"rows_{k}"] == rows.value(kind=k) - before[k]
     assert 0 < pruned["rows_needed"] <= pruned["rows_to_longest"] \
-        <= pruned["rows_scanned"]
+        == pruned["rows_scanned"] <= pruned["rows_bound"]
     assert pruned["slots"] == res.report.slot_count == 2
     slot_stats = [st for name, _, _, st in events if name == "repro.slot"]
     assert len(slot_stats) == 2
     assert all(st["rows"] == 16 and st["block"] == 256 for st in slot_stats)
-    assert pruned["rows_scanned"] == sum(16 * st["max_blocks"] * 256
-                                         for st in slot_stats)
+    assert pruned["rows_bound"] == sum(16 * st["max_blocks"] * 256
+                                       for st in slot_stats)
 
 
 def test_tracer_and_profiler_record_the_same_spans(small_ds, built_index,
@@ -494,4 +494,4 @@ def test_tracer_and_profiler_record_the_same_spans(small_ds, built_index,
             "fetch"} <= set(tree)
     pruned = next(sp for sp, _ in res.trace.walk() if sp.name == "pruned")
     assert {"slots", "rows_needed", "rows_scanned",
-            "rows_to_longest"} <= set(pruned.args)
+            "rows_to_longest", "rows_bound"} <= set(pruned.args)
