@@ -54,8 +54,8 @@ from .common import last_timing, time_call
 # traced-off number here must stay comparable with smoke history records
 SMOKE_N, SMOKE_D, SMOKE_Q, SMOKE_K, SMOKE_SEL = 800, 32, 16, 10, 0.05
 
-REQUIRED_SPANS = ("sharded_search", "plan", "shard-0", "shard-1", "merge",
-                  "search", "route")
+REQUIRED_SPANS = ("sharded_search", "plan", "shard", "fetch", "merge",
+                  "route")
 
 
 def noop_span_ns(iters: int = 200_000) -> float:

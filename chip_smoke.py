@@ -278,8 +278,7 @@ def four_chips(checks: Checks, n: int, seed: int) -> None:
           f"report={dep.build_report}", flush=True)
     checks("sharded/build_pool", dep.build_report["pool_size"] == 4,
            f"pool_size={dep.build_report['pool_size']}")
-    # a deployment's shards count as lost once idle past shard_timeout_s,
-    # so each one is stood up right before its requests
+    # each target is stood up right before its requests
     for tag, make, route in (
             ("sharded_pruned", lambda: dep, "pruned"),
             ("sharded_graph", lambda: dep, "graph"),

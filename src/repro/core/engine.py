@@ -149,6 +149,28 @@ def _scan_rows(scans: List[tuple]) -> np.ndarray:
     return out
 
 
+@dataclasses.dataclass
+class Dispatched:
+    """A request :meth:`QueryEngine.dispatch` started and
+    :meth:`QueryEngine.collect` finishes: its answers (``ids``, ``dists``,
+    padded, on the device unless the route had nothing to run), the pruned
+    route's ``scans`` (:func:`_scan_rows`), and the route span, still open
+    (``None`` for an empty batch)."""
+
+    Q: int
+    route: str
+    requested: str
+    est: Optional[np.ndarray]
+    hits: int
+    misses: int
+    slots: List[iv.PlanSlot]
+    ids: object
+    dists: object
+    scans: Optional[List[tuple]]
+    span: object
+    t0: float
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     """Engine-lifetime tuning for :class:`QueryEngine`, as one typed value.
@@ -598,6 +620,7 @@ class QueryEngine:
 
     def execute(self, request: SearchRequest) -> SearchResult:
         """Plan, route, and run one request; always returns a SearchResult.
+        :meth:`dispatch` then :meth:`collect`, in a row.
 
         ``request.trace=True`` (or a hit of ``EngineConfig.trace_sample``)
         records the request's span tree — plan, route decision, per-slot
@@ -605,34 +628,37 @@ class QueryEngine:
         shard of a :class:`repro.distributed.ShardedDeployment`, its spans
         join the deployment's trace instead (inner layers never finish an
         outer trace)."""
-        requested = request.route or self.default_route
-        if requested not in _ROUTES:
-            raise ValueError(f"route must be one of {_ROUTES}, got {requested!r}")
         wants_trace = request.trace
         if not wants_trace and self._trace_every:
             self._trace_seq += 1
             wants_trace = (self._trace_seq % self._trace_every) == 0
         tracer = obs.begin_request_trace() if wants_trace else None
-        t_exec = time.perf_counter()
         try:
             with obs.span("search") as root:
                 root.set("Q", len(request)).set("k", request.k)
-                root.set("mask", request.mask).set("requested", requested)
-                result = self._execute_routed(request, requested)
+                root.set("mask", request.mask)
+                p = self.dispatch(request)
+                root.set("requested", p.requested)
+                result = self.collect(p)
         finally:
             trace = obs.end_request_trace(tracer)
-        route = result.report.route if result.report is not None else requested
-        rm = self._route_metrics.get(route)
-        if rm is not None:
-            rm[0].inc()
-            rm[1].inc(float(len(request)))
-            rm[2].record((time.perf_counter() - t_exec) * 1e3)
         if trace is not None:
             result = dataclasses.replace(result, trace=trace)
         return result
 
-    def _execute_routed(self, request: SearchRequest,
-                        requested: str) -> SearchResult:
+    def dispatch(self, request: SearchRequest,
+                 slots: Optional[List[iv.PlanSlot]] = None) -> Dispatched:
+        """Route the request and start its device work, without waiting on
+        the device: the answers' copy to the host is under way when this
+        returns. ``slots``, when given, is the request's plan (a
+        :class:`repro.distributed.ShardedDeployment` plans once for all its
+        shards); otherwise the graph and pruned routes plan here. Finish
+        with :meth:`collect`, on any device context."""
+        requested = request.route or self.default_route
+        if requested not in _ROUTES:
+            raise ValueError(f"route must be one of {_ROUTES}, "
+                             f"got {requested!r}")
+        t0 = time.perf_counter()
         queries, qlo, qhi = request.vectors, request.qlo, request.qhi
         mask, k = request.mask, request.k
         Q = len(request)
@@ -647,19 +673,21 @@ class QueryEngine:
                     rsp.set("chosen", route)
                     rsp.set("est_mean", round(float(est.mean()), 6))
                     rsp.set("cache_hits", hits).set("cache_misses", misses)
+        if route not in (ROUTE_GRAPH, ROUTE_PRUNED):
+            slots = []
         if Q == 0:
             ids, d = _empty_result(0, k)
-            return SearchResult(ids, d, RouteReport(
-                route=route, requested=requested, est_selectivity=est,
-                slot_count=0, variants=()))
+            return Dispatched(0, route, requested, est, hits, misses, [],
+                              ids, d, None, None, t0)
         self.route_counts[route] = self.route_counts.get(route, 0) + 1
-        with obs.span("plan") as psp:
-            slots = (self.plan(mask, qlo, qhi) if route in (ROUTE_GRAPH,
-                                                            ROUTE_PRUNED)
-                     else [])
-            psp.set("slots", len(slots))
+        if slots is None:
+            with obs.span("plan") as psp:
+                slots = self.plan(mask, qlo, qhi)
+                psp.set("slots", len(slots))
         scans = None
-        with obs.span(route) as rsp:
+        # the route span stays open until collect() has counted the scans
+        rsp = obs.span(route)
+        try:
             if route == ROUTE_FLAT:
                 ids, d = self._run_flat(queries, qlo, qhi, mask, k)
             elif route == ROUTE_PRUNED:
@@ -672,15 +700,35 @@ class QueryEngine:
                                          chunk=request.chunk)
             else:
                 raise ValueError(f"unknown route {route!r}")
+        except BaseException:
+            rsp.stop()
+            raise
+        for a in (ids, d):
+            if isinstance(a, jax.Array):
+                a.copy_to_host_async()
+        return Dispatched(Q, route, requested, est, hits, misses, slots, ids,
+                          d, scans, rsp, t0)
+
+    def collect(self, p: Dispatched) -> SearchResult:
+        """Wait for the answers of a :meth:`dispatch` and report them."""
+        ids, d = p.ids, p.dists
+        if p.span is not None:
             # the host waits here until the answers are on it
             with obs.span("fetch"):
-                ids, d = np.asarray(ids[:Q]), np.asarray(d[:Q])
-            if scans is not None:
-                self._count_scans(scans, rsp)
-        report = RouteReport(route=route, requested=requested,
-                             est_selectivity=est, slot_count=len(slots),
-                             variants=tuple(s.variant for s in slots),
-                             cache_hits=hits, cache_misses=misses)
+                ids, d = np.asarray(ids)[:p.Q], np.asarray(d)[:p.Q]
+            if p.scans is not None:
+                self._count_scans(p.scans, p.span)
+            p.span.stop()
+        report = RouteReport(route=p.route, requested=p.requested,
+                             est_selectivity=p.est,
+                             slot_count=len(p.slots),
+                             variants=tuple(s.variant for s in p.slots),
+                             cache_hits=p.hits, cache_misses=p.misses)
+        rm = self._route_metrics.get(p.route)
+        if rm is not None:
+            rm[0].inc()
+            rm[1].inc(float(p.Q))
+            rm[2].record((time.perf_counter() - p.t0) * 1e3)
         return SearchResult(ids, d, report)
 
     # Convenience fixed-route entry points (legacy tuple returns).

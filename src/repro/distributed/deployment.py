@@ -5,9 +5,9 @@ The corpus partitions across the shards of a device mesh
 fans out to every shard, runs the *existing* per-shard routes locally (the
 exact pruned scan, the wavefront graph search, or a whole streaming
 :class:`repro.streaming.SegmentedIndex` per shard), and the per-shard top-k
-lists are combined through the :mod:`repro.distributed.topk` merge schedules
-— ``all_gather`` for small meshes, ``tournament`` ppermute for pod-scale
-ones, or a host merge when no mesh is attached.
+lists are merged on the host. Every shard's device work is dispatched
+before any shard's answers are awaited, so the shards' devices run at once.
+Only the fused :meth:`ShardedDeployment.flat` path merges on the devices.
 
 Three shard layouts:
 
@@ -16,7 +16,9 @@ Three shard layouts:
   (every engine route available per shard; local ids are rebased to global
   row ids). Shard ``i``'s engine stages its arrays on, and runs on, the
   ``i``-th device of the mesh's corpus axis (of ``jax.devices()`` without a
-  mesh, round-robin).
+  mesh, round-robin). The shards share one attribute domain, so the
+  deployment plans a request once (:meth:`ShardedDeployment.plan`) and
+  every shard executes that plan.
 * :meth:`ShardedDeployment.from_segmented` — an existing
   :class:`repro.streaming.SegmentedIndex`'s frozen segments dealt round-robin
   onto shards (the delta buffer rides on shard 0). A snapshot view: segments
@@ -24,8 +26,11 @@ Three shard layouts:
 * :meth:`ShardedDeployment.flat` — raw corpus slices served by the exact
   flat scan. The only layout with a fully *fused* device path: one
   ``shard_map`` call (:func:`repro.distributed.topk.sharded_flat_topk`)
-  computes local scans and the merge without ever materializing per-shard
-  results on host — this is what the ``--scale`` bench lane measures.
+  computes local scans and the merge with the
+  :mod:`repro.distributed.topk` schedules (``all_gather`` for small meshes,
+  ``tournament`` ppermute for pod-scale ones) without ever materializing
+  per-shard results on host — this is what the ``--scale`` bench lane
+  measures.
 
 Fan-in width: ``DeploymentSpec.per_shard_k`` caps how many candidates each
 shard contributes to the merge. ``k' == k`` reproduces the single-device
@@ -33,12 +38,12 @@ answer exactly (every global top-k member lives in some shard's local
 top-k); ``k' < k`` trades recall for merge traffic (bytes ∝ D·Q·k') — the
 recall-QPS pareto knob the scale bench sweeps.
 
-Fault handling (:mod:`repro.distributed.fault`): shards ping a
-:class:`HeartbeatRegistry` on every answer; a shard marked failed
-(:meth:`fail`), timed out past ``shard_timeout_s``, or raising mid-search
-contributes only sentinel rows. The request still answers — a
-degraded-recall :class:`repro.core.SearchResult` with the lost shards in
-``report.missing_shards`` and ``result.degraded == True`` — never an error.
+Fault handling: a shard marked failed (:meth:`fail`) or raising
+mid-search contributes only sentinel rows; a shard that raised is sent the
+next request again, and :meth:`restore` brings back a failed one. The
+request still answers — a degraded-recall :class:`repro.core.SearchResult`
+with the lost shards in ``report.missing_shards`` and
+``result.degraded == True`` — never an error.
 """
 from __future__ import annotations
 
@@ -51,6 +56,7 @@ import numpy as np
 import jax
 
 from repro import obs
+from repro.core import intervals as iv
 from repro.core.api import (IndexSpec, RouteReport, SearchRequest,
                             SearchResult, ShardReport)
 from repro.core.engine import EngineConfig, QueryEngine
@@ -59,8 +65,7 @@ from repro.core.hnsw import NO_EDGE
 from repro.core.mstg import MSTGIndex
 from repro.core.parallel import pool_size, run_build_pool
 
-from .fault import HeartbeatRegistry
-from .topk import resolve_merge, sharded_flat_topk, sharded_topk_merge
+from .topk import resolve_merge, sharded_flat_topk
 
 _MERGES = ("auto", "all_gather", "tournament", "host")
 
@@ -78,9 +83,12 @@ class DeploymentSpec:
     corpus_axis : str
         Mesh axis the corpus partitions over.
     merge : str
-        ``all_gather`` | ``tournament`` | ``host`` | ``auto``. ``auto``
-        resolves to ``host`` without a mesh, ``all_gather`` for D <= 8, and
-        ``tournament`` for power-of-two D > 8.
+        ``all_gather`` | ``tournament`` | ``host`` | ``auto``: the merge of
+        the fused :meth:`ShardedDeployment.flat` path. ``auto`` resolves to
+        ``host`` without a mesh, ``all_gather`` for D <= 8, and
+        ``tournament`` for power-of-two D > 8. Every other layout merges
+        its shards' answers on the host, and takes only ``auto`` or
+        ``host``.
     per_shard_k : int
         Per-shard fan-in width k' (0 = the request's full k). ``k' == k`` is
         exact relative to single-device; smaller trades recall for merge
@@ -105,8 +113,6 @@ class DeploymentSpec:
         state: it never changes the built shards, only the wall clock, and
         the pool degrades to the serial loop on platforms without process
         support.
-    shard_timeout_s : float
-        Heartbeat staleness beyond which a shard counts as lost.
     """
 
     n_shards: int = 1
@@ -116,7 +122,6 @@ class DeploymentSpec:
     engine: EngineConfig = dataclasses.field(default_factory=EngineConfig)
     index: Optional[IndexSpec] = None
     build_workers: int = 0
-    shard_timeout_s: float = 30.0
 
     def __post_init__(self):
         if self.n_shards < 1:
@@ -154,9 +159,9 @@ def _shard_build_task(args):
     back as its save payload — plain numpy arrays + a meta dict — rather
     than the live object, and reports the in-worker build seconds so the
     parent can attribute wall clock per shard."""
-    i, ispec, vectors, lo, hi = args
+    i, ispec, vectors, lo, hi, domain = args
     t0 = time.perf_counter()
-    idx = MSTGIndex.build(ispec, vectors, lo, hi)
+    idx = MSTGIndex.build(ispec, vectors, lo, hi, domain=domain)
     arrays, meta = idx.to_payload()
     return i, arrays, meta, time.perf_counter() - t0
 
@@ -212,13 +217,16 @@ class ShardedDeployment:
         self.spec = spec
         self.mesh = mesh
         self._flat = _flat_arrays      # (corpus, lo, hi) for the fused path
+        # the engine whose planner serves every shard (build(): one domain)
+        self._planner: Optional[QueryEngine] = None
         self._failed: set = set()
         self.build_report: Optional[dict] = None
-        self.heartbeats = HeartbeatRegistry(timeout_s=spec.shard_timeout_s)
-        now = time.time()
-        for s in self.shards:
-            self.heartbeats.ping(s.name, 0, now=now)
-        self._step = 0
+        calls = obs.get_registry().counter(
+            "deployment_shard_calls_total",
+            "Engine calls dispatched to each shard of a deployment",
+            labels=("shard",))
+        self._m_calls = [calls.labels(shard=str(i))
+                         for i in range(len(self.shards))]
 
     # ---- constructors ----
     @classmethod
@@ -233,11 +241,14 @@ class ShardedDeployment:
         carries a ``build_report`` dict — pool size, wall seconds, per-shard
         build seconds, rows/sec — for bench attribution."""
         spec = spec or DeploymentSpec()
+        _check_host_merge(spec)
         vectors = np.ascontiguousarray(vectors, np.float32)
         lo = np.asarray(lo, np.float64)
         hi = np.asarray(hi, np.float64)
         ispec = spec.index or IndexSpec()
         n = vectors.shape[0]
+        # one domain over the whole corpus: one plan is valid on every shard
+        domain = iv.AttributeDomain.from_ranges(lo, hi)
         bounds = np.linspace(0, n, spec.n_shards + 1, dtype=np.int64)
         slices = [(int(bounds[i]), int(bounds[i + 1]))
                   for i in range(spec.n_shards)]
@@ -246,7 +257,7 @@ class ShardedDeployment:
         indexes: List[MSTGIndex] = []
         results = run_build_pool(
             _shard_build_task,
-            [(i, ispec, vectors[a:b], lo[a:b], hi[a:b])
+            [(i, ispec, vectors[a:b], lo[a:b], hi[a:b], domain)
              for i, (a, b) in enumerate(slices)],
             workers=spec.build_workers, label="shard")
         if results is not None:
@@ -256,8 +267,8 @@ class ShardedDeployment:
         else:
             for a, b in slices:
                 t0 = time.perf_counter()
-                indexes.append(
-                    MSTGIndex.build(ispec, vectors[a:b], lo[a:b], hi[a:b]))
+                indexes.append(MSTGIndex.build(ispec, vectors[a:b], lo[a:b],
+                                               hi[a:b], domain=domain))
                 shard_secs.append(time.perf_counter() - t0)
         shards = []
         for i, (idx, (a, b), dev) in enumerate(zip(
@@ -268,6 +279,7 @@ class ShardedDeployment:
             shards.append(_Shard(f"shard-{i}", engine, b - a, a, dev))
         wall = time.perf_counter() - t_wall
         self = cls(shards, spec, mesh)
+        self._planner = shards[0].engine
         self.build_report = {
             # 0 when the pool fell back to the serial loop
             "pool_size": (pool_size(spec.build_workers, spec.n_shards)
@@ -287,6 +299,7 @@ class ShardedDeployment:
         source, not copied — a snapshot view; re-derive after mutations."""
         from repro.streaming.segmented import SegmentedIndex
         spec = spec or DeploymentSpec()
+        _check_host_merge(spec)
         shards = []
         for i in range(spec.n_shards):
             view = SegmentedIndex(segmented.spec, policy=segmented.policy,
@@ -326,21 +339,31 @@ class ShardedDeployment:
 
     def restore(self, shard: int) -> None:
         self._failed.discard(int(shard))
-        self.heartbeats.ping(self.shards[shard].name, self._step)
 
     def _alive(self) -> np.ndarray:
-        """(D,) bool — failed or heartbeat-timed-out shards are down."""
-        dead = set(self.heartbeats.dead_workers())
-        return np.array([(i not in self._failed
-                          and s.name not in dead)
-                         for i, s in enumerate(self.shards)], bool)
+        """(D,) bool — shards marked failed are down."""
+        return np.array([i not in self._failed
+                         for i in range(len(self.shards))], bool)
+
+    # ---- planning ----
+    def plan(self, mask: int, qlo: np.ndarray, qhi: np.ndarray
+             ) -> List[iv.PlanSlot]:
+        """The Theorem 4.1 plan of a batch, by the planner
+        :meth:`repro.core.QueryEngine.plan` runs; one plan serves every
+        shard of a :meth:`build` deployment, whose shards share one
+        attribute domain."""
+        if self._planner is None:
+            raise ValueError("only a ShardedDeployment.build deployment "
+                             "plans: its shards share one attribute domain")
+        return self._planner.plan(mask, qlo, qhi)
 
     # ---- execution ----
     def execute(self, request: SearchRequest) -> SearchResult:
         """Fan one request out over the shards and merge. With
-        ``request.trace=True`` the deployment owns the root trace — per-shard
-        engine spans nest under ``shard-i`` — and the finished
-        :class:`repro.obs.Trace` rides back on ``SearchResult.trace``."""
+        ``request.trace=True`` the deployment owns the root trace — each
+        shard's engine dispatch nests under its ``shard`` span — and the
+        finished :class:`repro.obs.Trace` rides back on
+        ``SearchResult.trace``."""
         if not isinstance(request, SearchRequest):
             raise TypeError("ShardedDeployment serves the declarative API "
                             "only; pass a repro.core.SearchRequest")
@@ -358,78 +381,90 @@ class ShardedDeployment:
 
     def _execute_sharded(self, request: SearchRequest) -> SearchResult:
         D, Q, k = self.spec.n_shards, len(request), request.k
+        fused = (self._flat is not None and self.mesh is not None
+                 and self.spec.merge != "host")
         with obs.span("plan") as psp:
             k_loc = min(self.spec.per_shard_k, k) if self.spec.per_shard_k \
                 else k
-            merge = resolve_merge(self.spec.merge, D) \
-                if (self.mesh is not None and self.spec.merge != "host") \
-                else "host"
+            merge = resolve_merge(self.spec.merge, D) if fused else "host"
             alive = self._alive()
             psp.set("merge", merge).set("k_loc", k_loc)
             psp.set("alive", int(alive.sum()))
-        self._step += 1
-        if self._flat is not None and merge != "host":
+            slots = None
+            if (self._planner is not None and Q
+                    and (request.route or self.spec.engine.route) != "flat"):
+                try:
+                    slots = self.plan(request.mask, request.qlo, request.qhi)
+                    psp.set("slots", len(slots))
+                except ValueError:
+                    pass     # each shard plans, and fails, on its own
+        if fused:
             return self._execute_flat_fused(request, k_loc, merge, alive)
 
         ids = np.full((D, Q, k_loc), NO_EDGE, np.int64)
         dists = np.full((D, Q, k_loc), np.inf, np.float32)
-        reports: List[ShardReport] = []
-        missing: List[int] = []
-        slot_total = 0
+        reports: List[Optional[ShardReport]] = [None] * D
         variants: List[str] = []
+        # every live shard's device work starts before any answer is awaited
+        pending = []
         for i, shard in enumerate(self.shards):
             if not alive[i]:
-                reports.append(ShardReport(shard=i, n=shard.n, route="lost",
-                                           alive=False, k_fetched=0))
-                missing.append(i)
+                reports[i] = ShardReport(shard=i, n=shard.n, route="lost",
+                                         alive=False, k_fetched=0)
                 continue
+            self._m_calls[i].inc()
             t0 = time.perf_counter()
-            ssp = obs.span(f"shard-{i}")
-            try:
-                li, ld, rep = self._run_shard(shard, request, k_loc)
-            except Exception:
-                # a shard raising mid-search is a lost shard, not a lost
-                # request: sentinel rows, flagged, never re-raised
-                ssp.set("alive", False).stop()
-                reports.append(ShardReport(shard=i, n=shard.n, route="error",
-                                           alive=False, k_fetched=0))
-                missing.append(i)
-                continue
-            ssp.set("n", shard.n).set("route", rep.route if rep else "flat")
-            ssp.stop()
-            ids[i], dists[i] = li, ld
-            self.heartbeats.ping(shard.name, self._step)
-            lat = time.perf_counter() - t0
-            slot_total += rep.slot_count if rep else 0
-            if rep:
-                variants.extend(rep.variants)
-            reports.append(ShardReport(
-                shard=i, n=shard.n,
-                route=rep.route if rep else "flat", k_fetched=k_loc,
-                latency_s=lat, slot_count=rep.slot_count if rep else 0))
+            with obs.span("shard") as ssp:
+                ssp.set("shard", i).set("rows", Q).set("n", shard.n)
+                try:
+                    finish, n_slots = self._dispatch_shard(shard, request,
+                                                           k_loc, slots)
+                except Exception:
+                    # a shard raising mid-search is a lost shard, not a lost
+                    # request: sentinel rows, flagged, never re-raised
+                    ssp.set("alive", False)
+                    reports[i] = _error_report(i, shard)
+                    continue
+                ssp.set("slots", n_slots)
+            pending.append((i, shard, t0, finish))
+        with obs.span("fetch"):
+            for i, shard, t0, finish in pending:
+                try:
+                    li, ld, rep = finish()
+                except Exception:
+                    reports[i] = _error_report(i, shard)
+                    continue
+                ids[i], dists[i] = self._local_answer(shard, li, ld, k_loc)
+                if rep:
+                    variants.extend(rep.variants)
+                reports[i] = ShardReport(
+                    shard=i, n=shard.n, route=rep.route if rep else "flat",
+                    k_fetched=k_loc, latency_s=time.perf_counter() - t0,
+                    slot_count=rep.slot_count if rep else 0)
         with obs.span("merge") as msp:
-            msp.set("schedule", merge)
-            if merge == "host":
-                gi, gd = _host_merge(ids, dists, k)
-            else:
-                gi, gd = sharded_topk_merge(self.mesh, ids, dists, k,
-                                            axis=self.spec.corpus_axis,
-                                            merge=merge, alive=alive)
-            gi, gd = np.asarray(gi), np.asarray(gd)
+            msp.set("schedule", merge).set("rows", ids.size)
+            gi, gd = _host_merge(ids, dists, k)
         report = RouteReport(
             route="sharded", requested=request.route or "auto",
-            est_selectivity=None, slot_count=slot_total,
+            est_selectivity=None,
+            slot_count=sum(r.slot_count for r in reports),
             variants=tuple(variants), shards=tuple(reports),
-            missing_shards=tuple(missing), merge=merge)
+            missing_shards=tuple(i for i, r in enumerate(reports)
+                                 if not r.alive),
+            merge=merge)
         return SearchResult(gi, gd, report)
 
     # QueryEngine-compatible alias (RetrievalServer & co).
     def search(self, request: SearchRequest) -> SearchResult:
         return self.execute(request)
 
-    def _run_shard(self, shard: _Shard, request: SearchRequest, k_loc: int):
-        """One shard's local answer as (Q, k_loc) global-id arrays."""
-        if shard.engine is None:      # flat layout, host path
+    def _dispatch_shard(self, shard: _Shard, request: SearchRequest,
+                        k_loc: int, slots: Optional[List[iv.PlanSlot]]):
+        """Start one shard's local answer on its device. Returns
+        ``(finish, slots)``: ``finish()`` waits for the answer and gives
+        ``(ids, dists, report)`` in the shard's own row ids (``report`` None
+        for the flat layout); ``slots`` counts the plan slots it runs."""
+        if shard.engine is None:      # flat layout, host-merged path
             corpus, lo, hi = self._flat
             a = shard.id_offset
             b = a + shard.n
@@ -438,23 +473,35 @@ class ShardedDeployment:
                 request.qlo.astype(np.float32), request.qhi.astype(np.float32),
                 mask=request.mask, k=min(k_loc, shard.n),
                 use_kernel=self.spec.engine.use_kernel)
-            li, ld, rep = np.asarray(li, np.int64), np.asarray(ld), None
-        else:
-            # the graph route's beam pool is ef wide; keep ef >= k' so the
-            # narrowed fan-in never truncates below the requested width
-            with jax.default_device(shard.device):
-                res = shard.engine.execute(dataclasses.replace(
-                    request, k=min(k_loc, max(shard.n, 1)),
-                    ef=max(request.ef, k_loc)))
-            li, ld, rep = (np.asarray(res.ids, np.int64),
-                           np.asarray(res.dists), res.report)
+            return (lambda: (li, ld, None)), 0
+        # the graph route's beam pool is ef wide; keep ef >= k' so the
+        # narrowed fan-in never truncates below the requested width
+        local = dataclasses.replace(request, k=min(k_loc, max(shard.n, 1)),
+                                    ef=max(request.ef, k_loc))
+        with jax.default_device(shard.device):
+            if isinstance(shard.engine, QueryEngine):
+                p = shard.engine.dispatch(local, slots=slots)
+
+                def finish():
+                    res = shard.engine.collect(p)
+                    return res.ids, res.dists, res.report
+                return finish, len(p.slots)
+            res = shard.engine.execute(local)
+        return (lambda: (res.ids, res.dists, res.report)), \
+            res.report.slot_count
+
+    @staticmethod
+    def _local_answer(shard: _Shard, li, ld, k_loc: int):
+        """One shard's answer as (Q, k_loc) global-id arrays."""
+        li = np.asarray(li, np.int64)
+        ld = np.asarray(ld, np.float32)
         if li.shape[1] < k_loc:      # tiny shard: pad to the uniform width
             pad = [(0, 0), (0, k_loc - li.shape[1])]
             li = np.pad(li, pad, constant_values=NO_EDGE)
             ld = np.pad(ld, pad, constant_values=np.inf)
         if shard.id_offset is not None:
             li = np.where(li >= 0, li + shard.id_offset, np.int64(NO_EDGE))
-        return li, ld.astype(np.float32), rep
+        return li, ld
 
     def _execute_flat_fused(self, request: SearchRequest, k_loc: int,
                             merge: str, alive: np.ndarray) -> SearchResult:
@@ -474,10 +521,6 @@ class ShardedDeployment:
             gi = np.asarray(gi, np.int64)
             gd = np.asarray(gd, np.float32)
         lat = time.perf_counter() - t0
-        now = time.time()
-        for i, s in enumerate(self.shards):
-            if alive[i]:
-                self.heartbeats.ping(s.name, self._step, now=now)
         reports = tuple(
             ShardReport(shard=i, n=s.n,
                         route="flat" if alive[i] else "lost",
@@ -491,3 +534,15 @@ class ShardedDeployment:
             est_selectivity=None, slot_count=0, variants=(),
             shards=reports, missing_shards=missing, merge=merge)
         return SearchResult(gi, gd, report)
+
+
+def _check_host_merge(spec: DeploymentSpec) -> None:
+    if spec.merge not in ("auto", "host"):
+        raise ValueError(f"merge={spec.merge!r} is a schedule of the fused "
+                         "flat layout; this layout merges on the host "
+                         "(merge='auto' or 'host')")
+
+
+def _error_report(i: int, shard: _Shard) -> ShardReport:
+    return ShardReport(shard=i, n=shard.n, route="error", alive=False,
+                       k_fetched=0)

@@ -103,45 +103,6 @@ def resolve_merge(merge: str, n_shards: int) -> str:
     return merge
 
 
-def sharded_topk_merge(mesh: Mesh, ids, dists, k: int, *,
-                       axis: str = "data", merge: str = "all_gather",
-                       alive=None) -> Tuple[np.ndarray, np.ndarray]:
-    """Merge host-stacked per-shard results through the device collectives.
-
-    ``ids``/``dists`` are (D, Q, k') arrays — one top-k' list per shard, as
-    produced by heterogeneous per-shard engines (graph / pruned / flat) whose
-    local searches ran on host. Each device receives its own shard's slice,
-    the chosen schedule (all_gather / tournament) merges across the mesh
-    axis, and the replicated (Q, k) global list is returned. ``alive`` is an
-    optional (D,) bool mask: a dead shard's list is replaced by sentinels
-    *on device*, modeling a shard that never answered."""
-    D = int(ids.shape[0])
-    if mesh.shape[axis] != D:
-        raise ValueError(f"stacked results have {D} shards but mesh axis "
-                         f"{axis!r} has size {mesh.shape[axis]}")
-    merge_fn = MERGE_SCHEDULES[resolve_merge(merge, D)]
-    ids = jnp.asarray(ids, jnp.int64 if jax.config.jax_enable_x64
-                      else jnp.int32)
-    dists = jnp.asarray(dists, jnp.float32)
-    alive_arr = (jnp.ones((D,), bool) if alive is None
-                 else jnp.asarray(alive, bool))
-
-    @functools.partial(
-        jax.shard_map, mesh=mesh,
-        in_specs=(P(axis, None, None), P(axis, None, None), P(None)),
-        out_specs=(P(None, None), P(None, None)),
-        check_vma=False)
-    def run(i, d, a):
-        i, d = i[0], d[0]                       # (Q, k') local slice
-        ok = a[jax.lax.axis_index(axis)]
-        i = jnp.where(ok, i, NO_EDGE)
-        d = jnp.where(ok, d, jnp.inf)
-        return merge_fn(i, d, k, axis)
-
-    gi, gd = run(ids, dists, alive_arr)
-    return np.asarray(gi, np.int64), np.asarray(gd, np.float32)
-
-
 def sharded_flat_topk(mesh: Mesh, corpus, lo, hi, queries, ql, qh, *, mask: int,
                       k: int, corpus_axis: str = "data",
                       merge: str = "all_gather", per_shard_k: int = 0,

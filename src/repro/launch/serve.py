@@ -12,8 +12,7 @@ RR-filtered ANN requests end to end (generate + retrieve). ``--streaming``
 backs the server with a :class:`repro.streaming.SegmentedIndex` instead and
 interleaves upserts/deletes with the query traffic. ``--shards N`` serves
 from a :class:`repro.distributed.ShardedDeployment` — per-shard MSTG
-engines merged through the device collectives when a mesh covers N, else
-the host merge. ``--async`` routes the same traffic through the
+engines, one a device of the mesh when it covers N, merged on the host. ``--async`` routes the same traffic through the
 continuous-batching :class:`repro.serving.AsyncRetrievalServer` (bounded
 admission, EDF deadlines, typed shedding) and prints its metrics
 snapshot."""
@@ -47,8 +46,8 @@ def main():
                     help="serve from a mutable SegmentedIndex and interleave "
                          "upserts/deletes with query traffic")
     ap.add_argument("--shards", type=int, default=0, metavar="N",
-                    help="serve from an N-shard ShardedDeployment (device "
-                         "merge when the mesh covers N, else host merge)")
+                    help="serve from an N-shard ShardedDeployment (a shard "
+                         "a device when the mesh covers N; host merge)")
     ap.add_argument("--async", dest="use_async", action="store_true",
                     help="serve through the continuous-batching async front "
                          "end (SLO admission + wavefront slot refill) and "
@@ -86,7 +85,7 @@ def main():
             ds.vectors, ds.lo, ds.hi, mesh=mesh,
             spec=DeploymentSpec(n_shards=args.shards, index=spec))
         print(f"sharded MSTG built: n={args.n} shards={args.shards} "
-              f"mesh={'yes' if mesh is not None else 'no (host merge)'} "
+              f"mesh={'yes' if mesh is not None else 'no'} "
               f"in {time.time()-t0:.1f}s")
     elif args.streaming:
         from repro.streaming import SegmentedIndex

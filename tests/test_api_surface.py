@@ -42,8 +42,8 @@ def test_engine_config_and_shard_report_construct():
 def test_distributed_surface_imports():
     from repro.distributed import (DeploymentSpec, HeartbeatRegistry,
                                    MERGE_SCHEDULES, ShardedDeployment,
-                                   resolve_merge, sharded_flat_topk,
-                                   sharded_topk_merge)  # noqa: F401
+                                   resolve_merge,
+                                   sharded_flat_topk)  # noqa: F401
     assert set(MERGE_SCHEDULES) == {"all_gather", "tournament"}
     assert resolve_merge("auto", 4) == "all_gather"
     assert resolve_merge("auto", 16) == "tournament"

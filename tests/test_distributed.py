@@ -16,8 +16,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.core import (ANY_OVERLAP, EngineConfig, IndexSpec, QueryEngine,
-                        SearchRequest)
+from repro.core import (ANY_OVERLAP, LEFT_OVERLAP, QUERY_CONTAINED,
+                        QUERY_CONTAINING, RIGHT_OVERLAP, EngineConfig,
+                        IndexSpec, QueryEngine, SearchRequest)
 from repro.core.hnsw import NO_EDGE
 from repro.distributed import (DeploymentSpec, ShardedDeployment,
                                sharded_flat_topk)
@@ -108,17 +109,20 @@ def test_shard_exception_and_heartbeat_timeout_flagged(small_ds):
     assert res.degraded and res.report.missing_shards == (1,)
     assert res.report.shards[1].route == "error"
     assert not res.report.shards[1].alive
-    # heartbeat staleness past shard_timeout_s counts every shard as lost
-    dep2 = ShardedDeployment.flat(
-        ds.vectors, ds.lo, ds.hi,
-        spec=DeploymentSpec(n_shards=2, shard_timeout_s=0.005))
+    # idle time never loses a shard, and a shard that raised is sent the
+    # next request again: its answer brings it back
+    dep2 = ShardedDeployment.flat(ds.vectors, ds.lo, ds.hi,
+                                  spec=DeploymentSpec(n_shards=2))
+    full = dep2.execute(req)
     time.sleep(0.02)
-    stale = dep2.execute(req)
-    assert stale.degraded and stale.report.missing_shards == (0, 1)
-    assert not stale.valid_mask.any()
-    for i in range(2):
-        dep2.restore(i)                      # restore pings the heartbeat
     assert not dep2.execute(req).degraded
+    dep2.shards[1].engine = object()         # shard 1 starts raising
+    res = dep2.execute(req)
+    assert res.degraded and res.report.missing_shards == (1,)
+    dep2.shards[1].engine = None
+    healed = dep2.execute(req)
+    assert not healed.degraded
+    np.testing.assert_array_equal(healed.ids, full.ids)
 
 
 def test_per_shard_k_narrowing_and_padding(small_ds):
@@ -217,6 +221,21 @@ def test_deployment_spec_validation(small_ds):
         dep.execute(ds.queries)
 
 
+@pytest.mark.parametrize("merge", ["all_gather", "tournament"])
+@pytest.mark.parametrize("layout", ["build", "from_segmented"])
+def test_device_merge_is_refused_off_the_flat_layout(small_ds, layout,
+                                                     merge):
+    """Only the fused flat layout merges on the devices; the others merge
+    on the host and refuse a device schedule rather than ignore it."""
+    ds = small_ds
+    spec = DeploymentSpec(n_shards=2, merge=merge)
+    with pytest.raises(ValueError, match="merges on the host"):
+        if layout == "build":
+            ShardedDeployment.build(ds.vectors, ds.lo, ds.hi, spec=spec)
+        else:
+            ShardedDeployment.from_segmented(object(), spec=spec)
+
+
 # ---- device merges: run under the 8-virtual-device CPU lane ----
 
 @needs8
@@ -268,7 +287,7 @@ def test_sharded_vs_single_parity_grid_8dev(small_ds, built_index, mask):
     for route in ("flat", "pruned", "graph"):
         res = dep.execute(SearchRequest(ds.queries, (qlo, qhi), mask, k=10,
                                         ef=64, route=route))
-        assert res.report.merge == "all_gather" and not res.degraded
+        assert res.report.merge == "host" and not res.degraded
         if route == "graph":
             assert res.recall_vs(exact) >= 0.9, (mask, route)
         else:
@@ -350,3 +369,198 @@ def test_sharded_flat_8dev_subprocess():
                        capture_output=True, text=True, timeout=600,
                        cwd=os.path.join(os.path.dirname(__file__), ".."), env=env)
     assert "OK-8DEV" in r.stdout, r.stdout + r.stderr
+
+
+# ---- build layout: one plan, every shard dispatched before any is awaited --
+
+# the five predicates of the benchmark's wide mix, and one disjunction
+WIDE_MASKS = (LEFT_OVERLAP, QUERY_CONTAINED, RIGHT_OVERLAP, QUERY_CONTAINING,
+              ANY_OVERLAP, LEFT_OVERLAP | RIGHT_OVERLAP)
+
+
+@pytest.fixture(scope="module")
+def odd_ds():
+    """603 rows: four shards of unequal size."""
+    return make_range_dataset(n=603, d=16, n_queries=64, quantize=32, seed=3)
+
+
+@pytest.fixture(scope="module")
+def pruned_dep(odd_ds):
+    ds = odd_ds
+    return ShardedDeployment.build(
+        ds.vectors, ds.lo, ds.hi,
+        spec=DeploymentSpec(n_shards=4, engine=EngineConfig(route="pruned"),
+                            index=IndexSpec(variants=("T", "Tp", "Tpp"),
+                                            builder="scan")))
+
+
+def assert_exact(ids, dists, ds, queries, qlo, qhi, mask, k):
+    """Served answers equal brute force + eval_predicate: distances to f32
+    rounding, ids wherever the distance at that rank is not tied."""
+    ref_ids, ref_d = brute_force_topk(ds.vectors, ds.lo, ds.hi, queries,
+                                      qlo, qhi, mask, k)
+    ids, dists = np.asarray(ids), np.asarray(dists)
+    np.testing.assert_allclose(dists, ref_d, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(ids < 0, np.isinf(ref_d))
+    close = np.isclose(ref_d[:, 1:], ref_d[:, :-1], rtol=1e-5, atol=1e-5)
+    tie = np.isinf(ref_d)
+    tie[:, 1:] |= close
+    tie[:, :-1] |= close
+    np.testing.assert_array_equal(ids[~tie], ref_ids[~tie])
+
+
+@pytest.mark.parametrize("Q", [1, 13, 64])
+@pytest.mark.parametrize("mask", WIDE_MASKS)
+def test_build_deployment_matches_brute_force(odd_ds, pruned_dep, mask, Q):
+    ds = odd_ds
+    qlo, qhi = make_queries(ds, mask, 0.1, seed=40 + mask)
+    req = SearchRequest(ds.queries[:Q], (qlo[:Q], qhi[:Q]), mask, k=10)
+    res = pruned_dep.execute(req)
+    assert not res.degraded and res.report.merge == "host"
+    assert [s.route for s in res.report.shards] == ["pruned"] * 4
+    assert_exact(res.ids, res.dists, ds, ds.queries[:Q], qlo[:Q], qhi[:Q],
+                 mask, 10)
+
+
+def test_build_shards_share_one_domain(odd_ds, pruned_dep):
+    doms = [s.engine.index.domain for s in pruned_dep.shards]
+    assert all(d is doms[0] for d in doms)
+    np.testing.assert_array_equal(
+        doms[0].values, np.unique(np.concatenate([odd_ds.lo, odd_ds.hi])))
+    assert [s.n for s in pruned_dep.shards] == [150, 151, 151, 151]
+
+
+def test_deployment_plans_once_per_request(odd_ds, pruned_dep, monkeypatch):
+    """The deployment plans each request once, through its own ``plan``,
+    and hands the plan to every shard; no shard engine plans on its own."""
+    from repro.core.mstg import MSTGIndex
+    ds = odd_ds
+    calls = []
+    plan_batch = MSTGIndex.plan_batch
+    monkeypatch.setattr(MSTGIndex, "plan_batch",
+                        lambda *a: calls.append("plan") or plan_batch(*a))
+    plan = pruned_dep.plan
+    monkeypatch.setattr(pruned_dep, "plan",
+                        lambda *a: calls.append("dep") or plan(*a))
+    qlo, qhi = make_queries(ds, ANY_OVERLAP, 0.1, seed=5)
+    for _ in range(3):
+        res = pruned_dep.execute(SearchRequest(ds.queries[:13],
+                                               (qlo[:13], qhi[:13]),
+                                               ANY_OVERLAP, k=10))
+        assert not res.degraded
+    assert calls == ["dep", "plan"] * 3
+    assert res.report.slot_count == 4 * len(plan(ANY_OVERLAP, qlo[:13],
+                                                 qhi[:13]))
+
+
+def test_shard_spans_end_before_the_fetch(odd_ds, pruned_dep):
+    """Every shard is dispatched before any shard's answers are awaited:
+    each ``shard`` span ends before the ``fetch`` span begins; each shard
+    call is counted on /metrics."""
+    from repro import obs
+    ds = odd_ds
+    qlo, qhi = make_queries(ds, ANY_OVERLAP, 0.1, seed=6)
+    req = SearchRequest(ds.queries[:13], (qlo[:13], qhi[:13]), ANY_OVERLAP,
+                        k=10)
+    counter = obs.get_registry().counter("deployment_shard_calls_total",
+                                         labels=("shard",))
+    before = [counter.value(shard=str(i)) for i in range(4)]
+    with obs.capture() as tr:
+        pruned_dep.execute(req)
+    spans = [sp for sp, _ in tr.trace().walk()]
+    shard = [sp for sp in spans if sp.name == "shard"]
+    (fetch,) = [sp for sp in spans if sp.name == "fetch"
+                and sp in tr.trace().roots[0].children]
+    assert [sp.args["shard"] for sp in shard] == [0, 1, 2, 3]
+    assert all(sp.args["rows"] == 13 and sp.args["slots"] > 0
+               for sp in shard)
+    assert max(sp.t_stop for sp in shard) <= fetch.t_start
+    (merge,) = [sp for sp in spans if sp.name == "merge"
+                and sp in tr.trace().roots[0].children]
+    assert merge.args["rows"] == 4 * 13 * 10
+    assert [counter.value(shard=str(i)) - before[i]
+            for i in range(4)] == [1, 1, 1, 1]
+
+
+@pytest.mark.parametrize("route", ["pruned", "flat", "graph"])
+def test_dispatch_then_collect_is_execute(small_ds, built_index, route):
+    """``execute`` is ``dispatch`` then ``collect``: the same answers, bit
+    for bit, and the same report."""
+    ds = small_ds
+    eng = QueryEngine(built_index)
+    for mask in (QUERY_CONTAINED, ANY_OVERLAP):
+        qlo, qhi = make_queries(ds, mask, 0.2, seed=mask)
+        req = SearchRequest(ds.queries, (qlo, qhi), mask, k=10, route=route)
+        a = eng.execute(req)
+        b = eng.collect(eng.dispatch(req))
+        np.testing.assert_array_equal(a.ids, b.ids)
+        np.testing.assert_array_equal(a.dists, b.dists)
+        assert repr(a.report) == repr(b.report)
+
+
+def test_fail_degrades_a_build_deployment(odd_ds, pruned_dep):
+    """fail() on a pruned build deployment: degraded answers with
+    missing_shards set, exact over the shards left; restore() heals."""
+    ds = odd_ds
+    qlo, qhi = make_queries(ds, ANY_OVERLAP, 0.3, seed=7)
+    req = SearchRequest(ds.queries[:13], (qlo[:13], qhi[:13]), ANY_OVERLAP,
+                        k=10)
+    pruned_dep.fail(1)
+    try:
+        res = pruned_dep.execute(req)
+    finally:
+        pruned_dep.restore(1)
+    assert res.degraded and res.report.missing_shards == (1,)
+    assert res.report.shards[1].route == "lost"
+    keep = np.ones(len(ds.lo), bool)
+    keep[150:301] = False                    # shard 1's rows
+    lo = np.where(keep, ds.lo, 2e9)          # rows no predicate can keep
+    ref_ids, ref_d = brute_force_topk(ds.vectors, lo, np.where(keep, ds.hi,
+                                                               2e9),
+                                      ds.queries[:13], qlo[:13], qhi[:13],
+                                      ANY_OVERLAP, 10)
+    np.testing.assert_allclose(res.dists, ref_d, rtol=1e-5, atol=1e-5)
+    assert not pruned_dep.execute(req).degraded
+
+
+_FOUR_DEVICE_PROG = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys
+    sys.path[:0] = ["src", "tests"]
+    import numpy as np
+    import jax
+    from repro.core import EngineConfig, IndexSpec, SearchRequest
+    from repro.data import make_range_dataset, make_queries
+    from repro.distributed import DeploymentSpec, ShardedDeployment
+    from test_distributed import WIDE_MASKS, assert_exact
+
+    ds = make_range_dataset(n=603, d=16, n_queries=64, quantize=32, seed=3)
+    dep = ShardedDeployment.build(
+        ds.vectors, ds.lo, ds.hi,
+        spec=DeploymentSpec(n_shards=4, engine=EngineConfig(route="pruned"),
+                            index=IndexSpec(variants=("T", "Tp", "Tpp"),
+                                            builder="scan")))
+    for mask in WIDE_MASKS:
+        qlo, qhi = make_queries(ds, mask, 0.1, seed=40 + mask)
+        for Q in (1, 13, 64):
+            res = dep.execute(SearchRequest(ds.queries[:Q],
+                                            (qlo[:Q], qhi[:Q]), mask, k=10))
+            assert not res.degraded
+            assert_exact(res.ids, res.dists, ds, ds.queries[:Q], qlo[:Q],
+                         qhi[:Q], mask, 10)
+    homes = [s.engine.corpus.devices() for s in dep.shards]
+    assert homes == [{d} for d in jax.devices()], homes
+    print("OK-4DEV")
+""")
+
+
+def test_build_deployment_on_four_devices_subprocess():
+    """The same answers with each shard on its own (virtual) device."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_PROG],
+                       capture_output=True, text=True, timeout=600,
+                       cwd=os.path.join(os.path.dirname(__file__), ".."),
+                       env=env)
+    assert "OK-4DEV" in r.stdout, r.stdout + r.stderr[-3000:]

@@ -287,8 +287,8 @@ def test_sharded_trace_acceptance(small_ds, tmp_path):
     res = dep.execute(_req(ds, qlo, qhi, trace=True))   # route=None -> auto
     assert res.trace is not None
     names = res.trace.span_names()
-    for want in ("sharded_search", "plan", "shard-0", "shard-1", "merge",
-                 "search", "route"):
+    for want in ("sharded_search", "plan", "shard", "fetch", "merge",
+                 "route"):
         assert want in names, names
     path = res.trace.save(str(tmp_path / "trace.json"))
     with open(path) as f:
@@ -299,11 +299,11 @@ def test_sharded_trace_acceptance(small_ds, tmp_path):
     text = res.explain()
     assert "shard[0]" in text and "shard[1]" in text
     assert "merge: host" in text and "sharded_search" in text
-    # inner shard engines joined the outer trace: exactly one Trace, and the
-    # per-shard engine spans nest under their shard span
-    shard0 = next(sp for sp in res.trace.roots[0].children
-                  if sp.name == "shard-0")
-    assert [c.name for c in shard0.children] == ["search"]
+    # inner shard engines joined the outer trace: exactly one Trace, and each
+    # shard's engine dispatch nests under its shard span
+    shards = [sp for sp in res.trace.roots[0].children if sp.name == "shard"]
+    assert [sp.args["shard"] for sp in shards] == [0, 1]
+    assert [c.name for c in shards[0].children][0] == "route"
 
 
 # ---- serving: one snapshot schema from both servers ------------------------
