@@ -102,6 +102,24 @@ def flat_search_blocked(corpus, lo, hi, queries, ql, qh, *, mask: int, k: int,
     return top_i, top_d
 
 
+def _node_of_position(pos, starts, cum, levels, shift):
+    """Map candidate positions to the decomposition node that holds them.
+
+    ``pos`` is (B,) candidate positions; ``starts``, ``cum``, ``levels`` and
+    ``shift`` are (Q, P) per node: where its prefix starts and ends in
+    candidate space, its tree level, and its slice start in ``members``
+    less ``starts``. Position ``pos`` lies in the one node with ``starts <=
+    pos < cum`` (a node with an empty prefix holds none), so each value is
+    read by a select and a sum over the P nodes, with no gather. Returns
+    (Q, B) ``(level, member index)``; a position at or past the query's
+    total lies in no node and reads ``(0, pos)``."""
+    p = pos[None, None, :]
+    hit = (p >= starts[:, :, None]) & (p < cum[:, :, None])       # (Q, P, B)
+    lvl = jnp.sum(jnp.where(hit, levels[:, :, None], 0), axis=1)
+    midx = pos[None, :] + jnp.sum(jnp.where(hit, shift[:, :, None], 0), axis=1)
+    return lvl, midx
+
+
 @functools.partial(jax.jit, static_argnames=("pred_mask_bits", "k", "Kpad",
                                               "block", "max_blocks"))
 def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
@@ -111,6 +129,15 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
     fused distance + running top-k. ``pred_mask_bits`` re-checks the exact
     predicate on gathered candidates (cheap; guards rank-boundary ties and
     lets one variant serve any sub-mask of its plan).
+
+    A query's candidates are its P nodes' member prefixes laid end to end;
+    each block of the loop takes ``block`` positions of that space. A
+    position is mapped to its node by :func:`_node_of_position`, a compare
+    against the nodes' bounds and a masked sum over P, which reads the
+    node's level and member index with no gather from the (Q, P) tables.
+    The loop body's gathers are the member ids (from ``members`` laid flat),
+    their endpoints for the re-check, the candidate rows and the running
+    top-k.
 
     Returns ``(ids, dists, total, n_run)``: ``total`` is each query's (Q,)
     int32 candidate-prefix length, the rows its answer needs; ``n_run`` is
@@ -166,24 +193,29 @@ def _pruned_search_variant(arrays: dict, lo_attr, hi_attr, queries, ql, qh,
     cum = jnp.cumsum(plen, axis=1)
     starts = cum - plen                                           # (Q, P) in candidate space
     total = cum[:, -1]
+    shift = off - starts                                          # (Q, P)
 
     top_d = jnp.full((Q, k), INF, jnp.float32)
     top_i = jnp.full((Q, k), NO_EDGE, jnp.int32)
 
     n_run = jnp.minimum(max_blocks, (jnp.max(total) + block - 1) // block)
 
+    # member ids are read from the table laid flat: its relayout is a fresh
+    # buffer that the v5e compiler can place in on-chip memory for the whole
+    # loop, where the 2-D argument may stay in HBM (an id read there costs
+    # ~3x as much)
+    Lv, W = members.shape
+    if Lv * W >= 2**31:
+        raise ValueError(f"a member table of {Lv} x {W} ids overflows an "
+                         "int32 flat index")
+    flat_members = members.reshape(-1)
+
     def body(carry):
         blk, top_d, top_i = carry
         pos = blk * block + jnp.arange(block)                     # (B,) candidate positions
-        # map candidate position -> (node slot, offset within prefix)
-        slot = jnp.sum(pos[None, :, None] >= cum[:, None, :], axis=2)   # (Q, B)
-        slot = jnp.clip(slot, 0, P - 1)
-        inner = pos[None, :] - jnp.take_along_axis(starts, slot, 1)
+        lvl_b, midx = _node_of_position(pos, starts, cum, levels, shift)
         ok = pos[None, :] < total[:, None]
-        lvl_b = jnp.take_along_axis(levels, slot, 1)
-        off_b = jnp.take_along_axis(off, slot, 1)
-        midx = jnp.clip(off_b + inner, 0, members.shape[1] - 1)
-        cand = members[jnp.clip(lvl_b, 0, members.shape[0] - 1), midx]  # (Q, B)
+        cand = flat_members[jnp.clip(lvl_b * W + midx, 0, Lv * W - 1)]  # (Q, B)
         cand_safe = jnp.where(ok, cand, 0)
         # exact predicate re-check on raw endpoints
         sel = iv.eval_predicate(pred_mask_bits, lo_attr[cand_safe], hi_attr[cand_safe],

@@ -1,7 +1,9 @@
 """Vectorized planner (property-based vs the scalar Theorem 4.1 reference)
 and the QueryEngine facade (routing, padding, end-to-end recall)."""
 import dataclasses
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,9 +15,9 @@ from repro.core import (ANY_OVERLAP, QUERY_CONTAINED, QUERY_CONTAINING,
 from repro.core import segment_tree as st
 from repro.core.engine import (ROUTE_GRAPH, ROUTE_PRUNED, _next_pow2,
                                _scan_rows)
-from repro.core.flat import _pruned_search_variant
+from repro.core.flat import _node_of_position, _pruned_search_variant
 from repro.core.hnsw import NO_EDGE
-from repro.data import make_queries, brute_force_topk
+from repro.data import brute_force_topk, make_queries, make_range_dataset
 
 
 def _req(queries, qlo, qhi, mask, route=None, **kw):
@@ -213,16 +215,38 @@ def _assert_exact(ds, queries, qlo, qhi, mask, ids, d, k=10):
                                    d[qi, :got.size], rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("mask,Q,tier", [
-    *[(m, Q, "float32") for m in (1, 4, 12, ANY_OVERLAP) for Q in (12, 8)],
-    (ANY_OVERLAP, 12, "int8")])
-def test_pruned_scan_stops_at_longest_prefix(small_ds, built_index, mask, Q,
-                                             tier):
+@pytest.fixture(scope="module")
+def wide_ds():
+    """Unquantized endpoints: ~2,400 distinct values, so Kpad = 4096 and a
+    decomposition has P = 26 nodes (16 on the 32-value grid of small_ds)."""
+    return make_range_dataset(n=1500, d=16, n_queries=12, seed=7)
+
+
+@pytest.fixture(scope="module")
+def wide_index(wide_ds):
+    ds = wide_ds
+    return MSTGIndex(ds.vectors, ds.lo, ds.hi, variants=("T", "Tp", "Tpp"),
+                     builder="scan")
+
+
+@pytest.mark.parametrize("grid,mask,Q,tier", [
+    *[pytest.param("narrow", m, Q, "float32", id=f"{m}-{Q}-float32")
+      for m in (1, 4, 12, ANY_OVERLAP) for Q in (12, 8)],
+    pytest.param("narrow", ANY_OVERLAP, 12, "int8", id=f"{ANY_OVERLAP}-12-int8"),
+    *[pytest.param("wide", m, Q, tier, id=f"wide-{m}-{Q}-{tier}")
+      for m, Q, tier in ((1, 12, "float32"), (4, 8, "float32"),
+                         (12, 12, "float32"), (ANY_OVERLAP, 12, "float32"),
+                         (ANY_OVERLAP, 8, "int8"))]])
+def test_pruned_scan_stops_at_longest_prefix(request, grid, mask, Q, tier):
     """The slot scan's loop stops at the batch's longest candidate prefix
     whatever its cap and block: answers at the default cap, at the widest
-    cap and at many small blocks agree bit for bit, and with brute force."""
-    ds = small_ds
-    eng = QueryEngine(built_index, config=EngineConfig(storage_dtype=tier))
+    cap and at many small blocks agree bit for bit, and with brute force.
+    On the wide endpoint grid (P = 26 nodes a query) the ids are brute
+    force's, rank for rank."""
+    ds = request.getfixturevalue("small_ds" if grid == "narrow" else "wide_ds")
+    index = request.getfixturevalue("built_index" if grid == "narrow"
+                                    else "wide_index")
+    eng = QueryEngine(index, config=EngineConfig(storage_dtype=tier))
     qlo, qhi = make_queries(ds, mask, 0.15, seed=31)
     queries, qlo, qhi = ds.queries[:Q], qlo[:Q], qhi[:Q]
     runs = ({}, {"max_candidates": ds.vectors.shape[0]}, {"block": 8})
@@ -232,6 +256,11 @@ def test_pruned_scan_stops_at_longest_prefix(small_ds, built_index, mask, Q,
         np.testing.assert_array_equal(ids_o, ids)
         np.testing.assert_array_equal(d_o, d)
     _assert_exact(ds, queries, qlo, qhi, mask, ids, d)
+    if grid == "wide":
+        assert st.max_cover_nodes(index.variants["T"].Kpad) >= 26
+        tids, _ = brute_force_topk(ds.vectors, ds.lo, ds.hi, queries, qlo,
+                                   qhi, mask, 10)
+        np.testing.assert_array_equal(ids, tids)
     for kw in runs:
         *_, scans = eng._run_pruned(queries, qlo, qhi, mask, 10, **kw)
         for total, n_run, max_blocks, block in scans:
@@ -312,6 +341,108 @@ def test_pruned_scan_of_empty_tasks_runs_no_block(built_index):
     assert int(n_run) == 0 and not np.asarray(total).any()
     np.testing.assert_array_equal(np.asarray(ids), np.full((Q, k), NO_EDGE))
     assert np.isinf(np.asarray(d)).all()
+
+
+def _node_of_position_by_gather(pos, starts, cum, levels, off):
+    """The slot scan's mapping before the select: the node's slot by a
+    compare against ``cum``, then its start, level and slice start by
+    ``take_along_axis``."""
+    P = cum.shape[1]
+    slot = np.clip(np.sum(pos[None, :, None] >= cum[:, None, :], axis=2),
+                   0, P - 1)
+    inner = pos[None, :] - np.take_along_axis(starts, slot, 1)
+    return (np.take_along_axis(levels, slot, 1),
+            np.take_along_axis(off, slot, 1) + inner)
+
+
+@pytest.mark.parametrize("P", [16, 34])
+def test_node_of_position_matches_gather(P):
+    """The select over the P nodes reads the level and member index the
+    gathers read, at every position of every query's prefix: nodes empty
+    at this version, nodes past the decomposition (invalid, with junk
+    levels and offsets), a padded query with no node at all; positions at
+    or past a query's total read ``(0, pos)``."""
+    rng = np.random.default_rng(P)
+    Q, Lv, W = 9, P // 2, 5000
+    valid = rng.random((Q, P)) < 0.7
+    levels = np.where(valid, rng.integers(0, Lv, (Q, P)),
+                      rng.integers(-5, 50, (Q, P))).astype(np.int32)
+    off = np.where(valid, rng.integers(0, W - 64, (Q, P)),
+                   rng.integers(-W, 2 * W, (Q, P))).astype(np.int32)
+    plen = rng.integers(0, 64, (Q, P)) * (rng.random((Q, P)) < 0.6)
+    plen = np.where(valid, plen, 0).astype(np.int32)
+    plen[-1] = 0                                 # a padded query
+    cum = np.cumsum(plen, axis=1, dtype=np.int32)
+    starts = cum - plen
+    total = cum[:, -1]
+    assert total[-1] == 0 and total.max() > 256
+    pos = np.arange(int(total.max()) + 300, dtype=np.int32)
+    lvl, midx = (np.asarray(a) for a in _node_of_position(
+        jnp.asarray(pos), jnp.asarray(starts), jnp.asarray(cum),
+        jnp.asarray(levels), jnp.asarray(off - starts)))
+    want_lvl, want_midx = _node_of_position_by_gather(pos, starts, cum,
+                                                      levels, off)
+    inside = pos[None, :] < total[:, None]
+    np.testing.assert_array_equal(lvl[inside], want_lvl[inside])
+    np.testing.assert_array_equal(midx[inside], want_midx[inside])
+    assert (lvl[~inside] == 0).all()
+    np.testing.assert_array_equal(midx[~inside],
+                                  np.broadcast_to(pos, inside.shape)[~inside])
+
+
+def _gathers_in_while_bodies(jaxpr, in_body=False):
+    """Operand shapes of every gather inside a ``while`` body, walking
+    nested jaxprs (jit calls, conds, scans) too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if in_body and eqn.primitive.name == "gather":
+            found.append(tuple(eqn.invars[0].aval.shape))
+        for name, v in eqn.params.items():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _gathers_in_while_bodies(
+                        sub, in_body or (eqn.primitive.name == "while"
+                                         and name == "body_jaxpr"))
+    return found
+
+
+def _scan_jaxpr(tier, n, Qp=16, d=32, Kpad=128, block=256):
+    """The slot scan traced at these shapes, with no array made."""
+    Lv = st.num_levels(Kpad)
+    i32, f32 = jnp.int32, jnp.float32
+    sds = jax.ShapeDtypeStruct
+    arrays = dict(members=sds((Lv, n), i32), member_ver=sds((Lv, n), i32),
+                  node_off=sds((Lv, Kpad + 1), i32))
+    if tier == "int8":
+        arrays.update(codes=sds((n, d), jnp.int8), code_scale=sds((d,), f32),
+                      code_offset=sds((d,), f32), code_sq_norm=sds((n,), f32))
+    else:
+        arrays["vectors"] = sds((n, d), f32)
+    fn = functools.partial(_pruned_search_variant, pred_mask_bits=ANY_OVERLAP,
+                           k=10, Kpad=Kpad, block=block, max_blocks=4)
+    return jax.make_jaxpr(fn)(
+        arrays, sds((n,), f32), sds((n,), f32), sds((Qp, d), f32),
+        sds((Qp,), f32), sds((Qp,), f32), sds((Qp,), i32), sds((Qp,), i32),
+        sds((Qp,), i32))
+
+
+@pytest.mark.parametrize("tier", ["float32", "int8"])
+def test_scan_loop_reads_no_node_table_by_gather(tier):
+    """The slot scan's loop body maps positions to nodes with no gather
+    from a (Qp, P) table: its gathers read the members, the endpoints, the
+    corpus (or its codes) and the running top-k only."""
+    Qp, n, Kpad = 16, 4096, 128
+    shapes = _gathers_in_while_bodies(_scan_jaxpr(tier, n).jaxpr)
+    assert (st.num_levels(Kpad) * n,) in shapes  # the member ids, laid flat
+    assert (Qp, st.max_cover_nodes(Kpad)) not in shapes, shapes
+
+
+def test_scan_refuses_member_table_past_int32_index():
+    """Member ids are read by a flat int32 index: a member table of 2**31
+    ids or more is refused when the scan is traced."""
+    with pytest.raises(ValueError, match="int32"):
+        _scan_jaxpr("float32", 2**31 // st.num_levels(128))
 
 
 def test_engine_empty_batch_and_empty_predicate(built_index, small_ds):
